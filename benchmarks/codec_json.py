@@ -216,8 +216,8 @@ def _kv_attention_section(rng, use_kernel: bool, kv_t) -> dict:
     for t in kv_t:
         q = jnp.asarray(
             rng.normal(size=(KV_B, 1, h, KV_HD)).astype(np.float32))
-        kf = rng.normal(size=(KV_B, t, KV_HKV, KV_HD)).astype(np.float32)
-        vf = rng.normal(size=(KV_B, t, KV_HKV, KV_HD)).astype(np.float32)
+        kf = rng.normal(size=(KV_B, KV_HKV, t, KV_HD)).astype(np.float32)
+        vf = rng.normal(size=(KV_B, KV_HKV, t, KV_HD)).astype(np.float32)
         ref_row = None
         for spec in map(formats.get, KV_FORMATS):
             if spec.is_identity:
@@ -264,20 +264,19 @@ def _paged_attention_section(rng, use_kernel: bool, kv_t, ps: int) -> dict:
         npages = -(-t // ps)
         q = jnp.asarray(
             rng.normal(size=(KV_B, 1, h, KV_HD)).astype(np.float32))
-        kf = rng.normal(size=(KV_B, npages * ps, KV_HKV,
+        # head-major pages [P, Hkv, ps, hd]
+        kf = rng.normal(size=(KV_B * npages, KV_HKV, ps,
                               KV_HD)).astype(np.float32)
-        vf = rng.normal(size=(KV_B, npages * ps, KV_HKV,
+        vf = rng.normal(size=(KV_B * npages, KV_HKV, ps,
                               KV_HD)).astype(np.float32)
         table = jnp.arange(KV_B * npages, dtype=jnp.int32).reshape(
             KV_B, npages)
         ref_row = None
         for spec in map(formats.resolve, PAGED_FORMATS):
             if spec.is_identity:
-                kp = jnp.asarray(kf).reshape(-1, ps, KV_HKV, KV_HD)
-                vp = jnp.asarray(vf).reshape(-1, ps, KV_HKV, KV_HD)
+                kp, vp = jnp.asarray(kf), jnp.asarray(vf)
             else:
-                kp = spec.encode_tile(kf).reshape(-1, ps, KV_HKV, KV_HD)
-                vp = spec.encode_tile(vf).reshape(-1, ps, KV_HKV, KV_HD)
+                kp, vp = spec.encode_tile(kf), spec.encode_tile(vf)
             bytes_per = spec.bytes_per_elem(jnp.float32)
             attn = jax.jit(lambda a, kk, vv, tb, s=spec, t=t:
                            ops.paged_attention(a, kk, vv, tb, s, pos=t - 1,
@@ -572,22 +571,29 @@ def _faults_serving_rows(smoke: bool) -> dict:
 
 def _sharded_serving_rows(smoke: bool) -> dict:
     """Sharded serving rows (schema 8), measured by
-    ``benchmarks/serve_sharded.py`` in a subprocess: forcing the XLA
-    host-platform device count only works before jax initializes, and
-    this process imported jax long ago. The child prints its row dict
-    as the last stdout line; ``#``-prefixed progress lines above it
-    surface in our output on failure."""
+    ``benchmarks/serve_sharded.py``. On a TPU it runs in this process
+    over the real devices: a child could not reach a chip this process
+    holds. On the CPU it runs in a subprocess pinned to the CPU, because
+    forcing the XLA host-platform device count only works before the
+    backend starts, and this process started it long ago. The child
+    prints its row dict as the last stdout line; ``#``-prefixed progress
+    lines above it surface in our output on failure."""
     import os
     import subprocess
     import sys
 
     from repro.launch.env import host_env
 
+    if jax.default_backend() == "tpu":
+        from benchmarks import serve_sharded
+        return serve_sharded.rows(smoke)
+
     script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "serve_sharded.py")
     root = os.path.dirname(os.path.dirname(script))
     env = host_env(8)
     env["REPRO_HOST_DEVICES"] = "8"
+    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("PYTHONPATH", os.path.join(root, "src"))
     cmd = [sys.executable, script] + (["--smoke"] if smoke else [])
     out = subprocess.run(cmd, cwd=root, env=env, capture_output=True,

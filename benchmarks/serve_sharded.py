@@ -1,9 +1,11 @@
 """Sharded serving throughput rows (BENCH schema 8, ``serving_sharded``).
 
-Standalone on purpose: forcing host devices requires setting XLA flags
-before jax imports, so ``benchmarks/codec_json.py`` runs this script in
-a fresh subprocess (``REPRO_HOST_DEVICES=8``) and parses the JSON line
-it prints last. Direct use:
+On a TPU ``benchmarks/codec_json.py`` calls :func:`rows` in its own
+process, over the real devices. On the CPU, forcing host devices
+requires setting XLA flags before the backend starts, so it runs this
+script in a fresh subprocess pinned to the CPU
+(``REPRO_HOST_DEVICES=8``) and parses the JSON line it prints last.
+Direct use:
 
     REPRO_HOST_DEVICES=8 PYTHONPATH=src python benchmarks/serve_sharded.py --smoke
 
@@ -15,11 +17,13 @@ not per-step host round-trips.
 
 Throughput accounting — read before comparing rows: the forced CPU
 "devices" time-slice ONE physical core, so wall-clock cannot improve
-with tp here (every shard's FLOPs land on the same core, plus ring-hop
-overhead). ``tokens_per_s_wall`` is that raw wall number;
+with tp there (every shard's FLOPs land on the same core, plus ring-hop
+overhead). ``tokens_per_s_wall`` is that raw wall number; on the CPU
 ``tokens_per_s`` is device-normalized (``wall * tp``) — the throughput
 the same step graph delivers when each shard owns a real device,
-because each shard executes ``1/tp`` of the model per step. The
+because each shard executes ``1/tp`` of the model per step. On real
+devices the shards run in parallel and ``tokens_per_s`` is the wall
+number. The
 tp-scaling acceptance gate (``tools/check_bench_schema.py``) reads the
 device-normalized number; interconnect bytes are the analytic ring
 census from ``ShardPlan.step_interconnect_bytes`` (hop counts x wire
@@ -27,25 +31,19 @@ bytes-per-element), where compression is an exact ``n/32`` scaling.
 """
 
 import argparse
+import dataclasses
 import json
 import os
 import time
 
-N_DEV = int(os.environ.get("REPRO_HOST_DEVICES", "8"))
-os.environ["XLA_FLAGS"] = (
-    f"--xla_force_host_platform_device_count={N_DEV} "
-    + os.environ.get("XLA_FLAGS", ""))
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-import dataclasses  # noqa: E402
-
-import jax           # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np   # noqa: E402
-
-from repro.configs import get_arch                 # noqa: E402
-from repro.models import model as _model           # noqa: E402
-from repro.serve.engine import ServeEngine         # noqa: E402
-from repro.serve.shard import ShardPlan            # noqa: E402
+from repro.configs import get_arch
+from repro.models import model as _model
+from repro.serve.engine import ServeEngine
+from repro.serve.shard import ShardPlan
 
 WIRE = "takum16"
 DECODE_BATCH = 4
@@ -96,19 +94,17 @@ def time_steps(eng, prompts, steps: int):
     return time.perf_counter() - t0
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--smoke", action="store_true")
-    args = ap.parse_args()
-    steps = 8 if args.smoke else 32
-
-    cfg = bench_cfg(args.smoke)
+def rows(smoke: bool) -> dict:
+    """The ``serving_sharded`` rows, over this process's devices."""
+    steps = 8 if smoke else 32
+    forced = jax.default_backend() == "cpu"
+    cfg = bench_cfg(smoke)
     params = _model.init(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
     prompts = [list(map(int, rng.integers(1, cfg.vocab - 1, n)))
                for n in (12, 5, 9, 17)]
 
-    rows = {}
+    out = {}
     tps = [t for t in (1, 2, 4, 8) if t <= jax.device_count()]
     for tp in tps:
         for compress in (WIRE, None):
@@ -121,29 +117,45 @@ def main() -> int:
             pool = eng.scheduler().pool
             wall = DECODE_BATCH * steps / dt
             key = f"tp{tp}/{'on' if compress else 'off'}"
-            rows[key] = {
+            out[key] = {
                 "tp": tp,
                 "compress": compress,
                 "steps": steps,
                 "decode_batch": DECODE_BATCH,
                 "us": round(dt * 1e6, 2),
                 "tokens_per_s_wall": round(wall, 2),
-                "tokens_per_s": round(wall * tp, 2),
-                "normalization": "device (wall * tp; forced host "
-                                 "devices time-slice one CPU core)",
+                "tokens_per_s": round(wall * tp if forced else wall, 2),
+                "normalization": ("device (wall * tp; forced host "
+                                  "devices time-slice one CPU core)"
+                                  if forced else "wall (one device per "
+                                  "shard)"),
                 "interconnect_bytes_per_step":
                     plan.step_interconnect_bytes(cfg, DECODE_BATCH),
                 "pool_shard_bytes": plan.shard_pool_bytes(pool),
                 "path": "sharded_step" if tp > 1 else "single_device",
             }
             print(f"# {key}: {dt * 1e3:.1f} ms / {steps} steps, "
-                  f"wall {wall:.1f} tok/s, normalized "
-                  f"{wall * tp:.1f} tok/s, "
-                  f"{rows[key]['interconnect_bytes_per_step']} "
+                  f"wall {wall:.1f} tok/s, "
+                  f"{out[key]['tokens_per_s']} tok/s "
+                  f"({out[key]['normalization']}), "
+                  f"{out[key]['interconnect_bytes_per_step']} "
                   "interconnect B/step")
-    print(json.dumps(rows))
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--smoke", action="store_true")
+    args = ap.parse_args()
+    print(json.dumps(rows(args.smoke)))
     return 0
 
 
 if __name__ == "__main__":
+    # the forced host-device count must be in XLA_FLAGS before the CPU
+    # backend starts, i.e. before the first device query
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count="
+        f"{os.environ.get('REPRO_HOST_DEVICES', '8')} "
+        + os.environ.get("XLA_FLAGS", ""))
     raise SystemExit(main())

@@ -212,7 +212,7 @@ def encode(s, e, frac, n: int, *, wm: int, sticky=None,
     # case A: cut inside `low` (regime fully kept)
     body_a = safe_shl(regime_val, 2 + wm - cut) | safe_shr(low, cut)
     g_a = jnp.where(cut >= 1, bit(low, cut - 1), jnp.asarray(0, cdt))
-    rest_a_nz = jnp.where(cut >= 2, (low & mask(cut - 1, cdt)) != 0, False)
+    rest_a_nz = (cut >= 2) & ((low & mask(cut - 1, cdt)) != 0)
     # case B: cut inside the regime
     c2 = cut - (2 + wm)
     body_b = safe_shr(regime_val, c2)
@@ -221,7 +221,9 @@ def encode(s, e, frac, n: int, *, wm: int, sticky=None,
     in_a = cut <= 2 + wm
     body = jnp.where(in_a, body_a, body_b)
     g = jnp.where(in_a, g_a, g_b)
-    rest_nz = jnp.where(in_a, rest_a_nz, rest_b_nz) | sticky
+    # boolean select spelled as and/or: Mosaic cannot lower a select
+    # whose operands are booleans (it narrows i8 back to i1)
+    rest_nz = (in_a & rest_a_nz) | (~in_a & rest_b_nz) | sticky
 
     rd = body & mask(n - 1, cdt)         # positive-magnitude word
     ru = rd + jnp.asarray(1, cdt)
@@ -302,7 +304,7 @@ def posit_to_float(words, n: int, dtype=jnp.float32, *, variant: str = "2c"):
     if wf > sb:
         # emulate the oracle's int->float conversion: values wider than the
         # significand are rounded to sb significant bits first
-        t = bitops.floor_log2(jnp.maximum(mf, jnp.asarray(1, adt)))
+        t = bitops.floor_log2(bitops.clamp(mf, 1))
         sh1 = jnp.maximum(t - fb, 0)
         mf = jnp.where(sh1 > 0, safe_shl(_rne_shr(mf, sh1), sh1), mf)
     if wf > fb:
@@ -357,7 +359,7 @@ def float_to_posit(x, n: int):
     is_zero = (exp_f == 0) & (fr == 0)
     is_nan = (exp_f == 255) & (fr != 0)
     is_inf = (exp_f == 255) & (fr == 0)
-    b = bitops.floor_log2(jnp.maximum(fr, 1))
+    b = bitops.floor_log2(bitops.clamp(fr, 1))
     sub = exp_f == 0
     E = jnp.where(sub, b - 149, exp_f - 127)
     mant23 = jnp.where(sub, safe_shl(fr, 23 - b) & jnp.uint32(0x7FFFFF), fr)

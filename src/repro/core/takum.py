@@ -293,7 +293,10 @@ def encode(s, c, mant, n: int, *, wm: int, sticky=None,
     in_a = p >= 0
     body = jnp.where(in_a, body_a, body_b)
     g = jnp.where(in_a, g_a, g_b)
-    rest_nz = jnp.where(in_a, rest_a != 0, rest_b_nz | (mant != 0)) | sticky
+    # boolean select spelled as and/or: Mosaic cannot lower a select
+    # whose operands are booleans (it narrows i8 back to i1)
+    rest_nz = ((in_a & (rest_a != 0))
+               | (~in_a & (rest_b_nz | (mant != 0))) | sticky)
 
     rd = header | body
     ru = (rd + jnp.asarray(1, cdt)) & mask(n, cdt)
@@ -522,7 +525,7 @@ def float_to_takum(x, n: int, *, rounding: str = "rne", rng_bits=None):
     is_inf = (exp_f == 255) & (frac == 0)
 
     # normalise subnormals: value = frac * 2^-149 = (1 + f') * 2^(b - 149)
-    b = bitops.floor_log2(jnp.maximum(frac, 1))
+    b = bitops.floor_log2(bitops.clamp(frac, 1))
     sub = exp_f == 0
     E = jnp.where(sub, b - 149, exp_f - 127)
     mant23 = jnp.where(sub, safe_shl(frac, 23 - b) & jnp.uint32(0x7FFFFF), frac)
@@ -617,7 +620,7 @@ def takum_to_float(words, n: int, dtype=jnp.float32):
     if wf > sb:
         # emulate the oracle's int->float conversion: values wider than the
         # significand are rounded to sb significant bits first
-        t = bitops.floor_log2(jnp.maximum(mf, jnp.asarray(1, adt)))
+        t = bitops.floor_log2(bitops.clamp(mf, 1))
         sh1 = jnp.maximum(t - fb, 0)
         mf = jnp.where(sh1 > 0, safe_shl(_rne_shr(mf, sh1), sh1), mf)
     if wf > fb:

@@ -34,7 +34,6 @@ import jax                    # noqa: E402
 import jax.numpy as jnp       # noqa: E402
 import numpy as np            # noqa: E402
 from jax import lax           # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core.quant import QuantSpec           # noqa: E402
@@ -44,8 +43,15 @@ from repro.dist import sharding as shd           # noqa: E402
 TAKUM16 = QuantSpec(fmt="takum", n=16, scale="none")
 
 
+def _mesh(shape, axes):
+    # Auto axes: annotate() constrains shardings with
+    # with_sharding_constraint, which refuses Explicit mesh axes
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def _mesh1d(size=8):
-    return jax.make_mesh((size,), ("data",))
+    return _mesh((size,), ("data",))
 
 
 def check_reduce_scatter(mesh):
@@ -54,9 +60,9 @@ def check_reduce_scatter(mesh):
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.integers(-8, 8, size=(size, g)).astype(np.float32))
 
-    fn = shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda v: coll.ring_reduce_scatter(v[0], "data", size)[0][None],
-        mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False)
+        mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False))
     got = np.asarray(fn(x)).reshape(-1)
     want = np.asarray(x).sum(axis=0)  # rank r owns chunk r -> concat = sum
     np.testing.assert_array_equal(got, want)
@@ -67,10 +73,10 @@ def check_all_gather(mesh):
     c = 16
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.integers(-8, 8, size=(size, c)).astype(np.float32))
-    fn = shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda v: coll.ring_all_gather(v[0], "data", size)[None],
         mesh=mesh, in_specs=P("data"), out_specs=P("data", None),
-        check_rep=False)
+        check_vma=False))
     got = np.asarray(fn(x))
     want = np.tile(np.asarray(x).reshape(-1), (size, 1)).reshape(got.shape)
     np.testing.assert_array_equal(got, want)
@@ -86,11 +92,11 @@ def check_all_reduce(mesh, spec, exact: bool):
             np.float32)
     x = jnp.asarray(base)
 
-    fn = shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(_ar_local, size=size, spec=spec),
         mesh=mesh, in_specs=P("data"), out_specs=(P("data", None),
                                                   P("data", None)),
-        check_rep=False)
+        check_vma=False))
     y, resid = fn(x)
     y, resid = np.asarray(y), np.asarray(resid)
     want = base.sum(axis=0)
@@ -112,7 +118,7 @@ def _ar_local(v, *, size, spec):
 
 
 def check_annotate():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = _mesh((2, 4), ("data", "model"))
     rules = shd.RULES_2D
 
     @jax.jit
@@ -163,8 +169,8 @@ def check_size1():
         lambda v: coll.ring_all_gather(v, "data", 1),
         lambda v: coll.ring_all_reduce(v, "data", 1)[0],
     ):
-        got = shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                        check_rep=False)(x)
+        got = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P(),
+                                    out_specs=P(), check_vma=False))(x)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
     # both spec families through the same wire seam
     from repro import formats

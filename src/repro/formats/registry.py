@@ -203,20 +203,13 @@ class FormatSpec:
 def lut_enabled() -> bool:
     """Whether LUT decode hooks are active for this process.
 
-    ``REPRO_LUT_DECODE=1`` forces on, ``0`` forces off; unset defaults to
-    TPU only. The default is measured, not aesthetic: the 256-entry
-    gather is a VMEM-resident ``jnp.take`` that wins on the TPU VPU, but
-    XLA:CPU lowers it to a serial gather that loses badly to the computed
-    integer decode (~20x at 2M elements on this host — see
-    docs/formats.md). Read per call (trace-time only), so tests can flip
-    the env var without cache invalidation games.
+    Off unless ``REPRO_LUT_DECODE=1``. No measurement has shown the
+    table to win: XLA:CPU lowers the gather serially and loses to the
+    computed integer decode (~20x at 2M elements, docs/formats.md), and
+    it has not been timed on a TPU. Read per call (trace-time only), so
+    tests can flip the env var without cache invalidation games.
     """
-    v = os.environ.get("REPRO_LUT_DECODE", "")
-    if v == "1":
-        return True
-    if v == "0":
-        return False
-    return jax.default_backend() == "tpu"
+    return os.environ.get("REPRO_LUT_DECODE", "") == "1"
 
 
 # ---------------------------------------------------------------------------
@@ -273,16 +266,33 @@ def _posit8_lut_decode(words, n, dtype=jnp.float32):
     Pallas kernel bodies cannot capture array constants, so the table is
     built *inside* the traced body from a 2D iota (TPU requires >= 2D
     iotas) and the computed integer decode — at trace time this folds to
-    a VMEM constant tile, and each element costs one gather. Bit-identical
-    to the computed path by construction.
+    a constant ``(2, 128)`` tile. Mosaic gathers only along one axis of
+    a 2D array of the index's shape, so each word looks up its low seven
+    bits in both 128-lane halves of the table (``take_along_axis`` over
+    ``[R, 128]`` rows) and its top bit selects the half. Bit-identical to
+    the computed path by construction.
     """
     assert n == 8
     from repro.core import posit
     idx = (jax.lax.broadcasted_iota(jnp.int32, (2, 128), 0) * 128
            + jax.lax.broadcasted_iota(jnp.int32, (2, 128), 1))
-    tab = posit.posit_to_float(idx.astype(jnp.uint8), 8,
-                               dtype=dtype).reshape(256)
-    return jnp.take(tab, jnp.asarray(words).astype(jnp.int32))
+    tab = posit.posit_to_float(idx.astype(jnp.uint8), 8, dtype=dtype)
+    w = jnp.asarray(words).astype(jnp.int32)
+    shape, size = w.shape, w.size
+    if w.ndim != 2 or shape[1] != 128:  # kernel tiles are already [R, 128]
+        w = jnp.pad(w.reshape(-1), (0, -size % 128)).reshape(-1, 128)
+    rows = w.shape[0]
+    low = w & 127
+
+    def half(h):
+        return jnp.take_along_axis(
+            jnp.broadcast_to(tab[h:h + 1], (rows, 128)), low, axis=1,
+            mode="promise_in_bounds")
+
+    out = jnp.where(w >= 128, half(1), half(0))
+    if out.shape != shape:
+        out = out.reshape(-1)[:size].reshape(shape)
+    return out
 
 
 _KIND_HOOKS = {

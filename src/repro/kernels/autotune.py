@@ -323,7 +323,7 @@ def _sweep_all(log=print, write_defaults: bool = False) -> dict:
     for t in cj.KV_T:
         q = jnp.asarray(rng.normal(
             size=(cj.KV_B, 1, h, cj.KV_HD)).astype(np.float32))
-        kf = rng.normal(size=(cj.KV_B, t, cj.KV_HKV,
+        kf = rng.normal(size=(cj.KV_B, cj.KV_HKV, t,
                               cj.KV_HD)).astype(np.float32)
         for name in cj.KV_FORMATS:
             spec = formats.resolve(name)

@@ -84,11 +84,12 @@ def _prod_slab(xell, xflg, well, wflg, k):
     zero/NaR flags OR. Broadcasts (bm, 1) against (1, bn). Activation
     lanes arrive pre-decoded (once per call, in the dispatcher below) —
     only weights are decoded inside the grid, where the decode-once
-    scratch pays off across the M steps."""
-    xe = jax.lax.dynamic_slice_in_dim(xell, k, 1, axis=1)
-    xf = jax.lax.dynamic_slice_in_dim(xflg, k, 1, axis=1)
-    we = jax.lax.dynamic_slice_in_dim(well, k, 1, axis=0)
-    wg = jax.lax.dynamic_slice_in_dim(wflg, k, 1, axis=0)
+    scratch pays off across the M steps. ``k`` is a static index: Mosaic
+    has no dynamic slice along the lane dimension."""
+    xe = xell[:, k:k + 1]
+    xf = xflg[:, k:k + 1]
+    we = well[k:k + 1, :]
+    wg = wflg[k:k + 1, :]
     ell = xe + we
     s = (xf & 1) ^ (wg & 1)
     zero = ((xf >> 1) | (wg >> 1)) & 1
@@ -109,29 +110,23 @@ def _linear_fold(xell, xflg, well, wflg, *, wf: int):
     NaR operands become NaN at conversion and propagate through the sum
     (matching the XLA fallback's decode-to-NaN semantics)."""
     bm, bk = xell.shape
-    bn = well.shape[1]
-
-    def body(k, acc):
-        s, ell, zero, nar = _prod_slab(xell, xflg, well, wflg, k)
-        return acc + _lns_to_f32(s, ell, zero, nar, wf)
-
-    return jax.lax.fori_loop(0, bk, body, jnp.zeros((bm, bn), jnp.float32))
+    acc = jnp.zeros((bm, well.shape[1]), jnp.float32)
+    for k in range(bk):  # unrolled: the slab index must be static
+        acc = acc + _lns_to_f32(*_prod_slab(xell, xflg, well, wflg, k), wf)
+    return acc
 
 
 def _gauss_fold(xell, xflg, well, wflg, lut, state, *, wf: int):
     """Fold all bk product slabs into the logarithmic-domain state with
     the fixed-point Gauss-log addition (LUT + interpolation). NaR rides
     along as a sticky flag, ORed outside the Gauss add."""
-    bk = xell.shape[1]
-
-    def body(k, carry):
-        a_s, a_ell, a_zero, a_nar = carry
+    a_s, a_ell, a_zero, a_nar = state
+    for k in range(xell.shape[1]):  # unrolled: the slab index is static
         p_s, p_ell, p_zero, p_nar = _prod_slab(xell, xflg, well, wflg, k)
         a_s, a_ell, a_zero = lns.gauss_add_parts(
             a_s, a_ell, a_zero, p_s, p_ell, p_zero, lut, wf=wf)
-        return a_s, a_ell, a_zero, a_nar | p_nar
-
-    return jax.lax.fori_loop(0, bk, body, state)
+        a_nar = a_nar | p_nar
+    return a_s, a_ell, a_zero, a_nar
 
 
 # ---------------------------------------------------------------------------
@@ -279,7 +274,7 @@ def lns_matmul_kernel_call(x_words, w_words, spec: formats.FormatSpec, *,
         # WS grid: only j (N) is parallel — kk/i share the stripe state.
         # M-outer grid: each (i, j) owns a disjoint output/state block,
         # so both are parallel (as in takum_matmul's fallback).
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
             if ws else ("parallel", "parallel", "arbitrary"))
 

@@ -366,8 +366,8 @@ def takum_attention(q, k_cache, v_cache, n=0, fmt="none", *,
                     max_q_rows: int = MAX_ATTN_Q_ROWS):
     """Attention over a wire-format KV cache, decoded inside the kernel.
 
-    ``q [B, tq, H, hd]`` (any float dtype) attends over
-    ``k_cache``/``v_cache [B, Tmax, Hkv, hd]`` — raw wire words of any
+    ``q [B, tq, H, hd]`` (any float dtype) attends over the head-major
+    ``k_cache``/``v_cache [B, Hkv, Tmax, hd]`` — raw wire words of any
     registered format (``(fmt, n)`` resolve through the registry:
     ``("linear", 8)``, ``"takum16"``, ``"posit8"``, a ``FormatSpec`` …)
     or plain floats under the identity codec (``fmt="none"``: the
@@ -400,7 +400,7 @@ def takum_attention(q, k_cache, v_cache, n=0, fmt="none", *,
     """
     spec = formats.resolve(fmt, n)
     b, tq, h, hd = q.shape
-    tmax, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, tmax = k_cache.shape[1], k_cache.shape[2]
     if h % hkv:
         raise ValueError(f"n_heads {h} not a multiple of n_kv_heads {hkv}")
     g = h // hkv
@@ -425,8 +425,8 @@ def takum_attention(q, k_cache, v_cache, n=0, fmt="none", *,
     pad_t = -tmax % bk
     kw, vw = k_cache, v_cache
     if pad_t:  # zero words decode to 0.0 / is_zero and are causally masked
-        kw = jnp.pad(kw, ((0, 0), (0, pad_t), (0, 0), (0, 0)))
-        vw = jnp.pad(vw, ((0, 0), (0, pad_t), (0, 0), (0, 0)))
+        kw = jnp.pad(kw, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
+        vw = jnp.pad(vw, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
     start_arr = (jnp.zeros((b,), jnp.int32) if start is None
                  else jnp.asarray(start, jnp.int32).reshape(b))
@@ -444,8 +444,8 @@ def paged_attention(q, k_pool, v_pool, table, fmt="none", *, pos,
     """Decode-step attention over a *paged* wire-format KV cache.
 
     The serving scheduler's counterpart of :func:`takum_attention`:
-    instead of one contiguous ``[B, Tmax, Hkv, hd]`` cache, K/V live in
-    a shared ``[num_pages, page_size, Hkv, hd]`` pool (wire words of any
+    instead of one contiguous ``[B, Hkv, Tmax, hd]`` cache, K/V live in
+    a shared ``[num_pages, Hkv, page_size, hd]`` pool (wire words of any
     registered format, or floats under the identity codec) and
     ``table [B, NP]`` maps each sequence's kk-th KV block to a pool
     page. ``q [B, 1, H, hd]`` is one decode step for a continuous batch:
@@ -470,11 +470,10 @@ def paged_attention(q, k_pool, v_pool, table, fmt="none", *, pos,
             f"paged_attention is decode-only (tq == 1), got tq={tq}; "
             "prefill runs on the contiguous cache and is scattered into "
             "pages by the scheduler")
-    hkv = k_pool.shape[2]
+    hkv, ps = k_pool.shape[1], k_pool.shape[2]
     if h % hkv:
         raise ValueError(f"n_heads {h} not a multiple of n_kv_heads {hkv}")
     g = h // hkv
-    ps = k_pool.shape[1]
     if use_kernel is None:
         use_kernel = not interpret_default()
     if not use_kernel:
@@ -496,6 +495,13 @@ def paged_attention(q, k_pool, v_pool, table, fmt="none", *, pos,
         ps=ps, window=window, interpret=interpret)
     out = out4[:, :, :rows].reshape(b, hkv, g, 1, hd)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, 1, h, hd)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _encode_words(spec, w):
+    # one fused program: run eagerly, the codec would hold several
+    # lane-wide temporaries of the whole weight at once
+    return spec.encode_tile(w.astype(jnp.float32))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -525,7 +531,7 @@ class WireMatrix:
     def encode(cls, w, n=None, *, block: tuple | None = None,
                fmt="linear"):
         spec = formats.resolve_wire(fmt, n)
-        return cls(spec.encode_tile(jnp.asarray(w, jnp.float32)), block=block,
+        return cls(_encode_words(spec, jnp.asarray(w)), block=block,
                    fmt=spec)
 
     # back-compat accessors (the spec carries the identity)

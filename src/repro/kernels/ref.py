@@ -95,7 +95,7 @@ def attention_ref(q, k_cache, v_cache, n, fmt="none", *, pos,
     Exactly the pre-kernel serving path: the **whole** KV cache is
     decoded to f32 up front (the HBM materialisation the Pallas kernel
     exists to avoid) and dense masked attention runs over it. q is
-    ``[B, tq, H, hd]``, the caches ``[B, Tmax, Hkv, hd]`` wire words
+    ``[B, tq, H, hd]``, the head-major caches ``[B, Hkv, Tmax, hd]`` wire words
     (floats for the identity codec); ``pos`` is the position of
     ``q[:, 0]``, ``start`` the per-sequence first valid key position
     (left padding), ``window`` a sliding-window length (0 = full
@@ -112,12 +112,12 @@ def attention_ref(q, k_cache, v_cache, n, fmt="none", *, pos,
         k = spec.decode_tile(k_cache, dtype=jnp.float32)
         v = spec.decode_tile(v_cache, dtype=jnp.float32)
     b, tq, h, hd = q.shape
-    tk, hkv = k.shape[1], k.shape[2]
+    hkv, tk = k.shape[1], k.shape[2]
     g = h // hkv
     q5 = q.reshape(b, tq, hkv, g, hd)
     if not spec.is_identity:
         q5 = q5.astype(jnp.float32)
-    scores = (jnp.einsum("bqkgd,bskd->bkgqs", q5, k).astype(jnp.float32)
+    scores = (jnp.einsum("bqkgd,bksd->bkgqs", q5, k).astype(jnp.float32)
               * (hd ** -0.5))
     qi = (pos + jnp.arange(tq))[None, None, None, :, None]
     kj = jnp.arange(tk)[None, None, None, None, :]
@@ -128,7 +128,7 @@ def attention_ref(q, k_cache, v_cache, n, fmt="none", *, pos,
         m = m & (kj >= jnp.asarray(start)[:, None, None, None, None])
     scores = jnp.where(m, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(v.dtype), v)
+    out = jnp.einsum("bkgqs,bksd->bqkgd", probs.astype(v.dtype), v)
     return out.reshape(b, tq, h, hd).astype(out_dtype)
 
 
@@ -137,10 +137,10 @@ def paged_attention_ref(q, k_pool, v_pool, table, fmt="none", *, pos,
                         out_dtype=jnp.float32):
     """Gather-then-attend oracle for the paged decode kernel.
 
-    ``k_pool``/``v_pool`` are ``[P, ps, Hkv, hd]`` page pools (wire words
+    ``k_pool``/``v_pool`` are ``[P, Hkv, ps, hd]`` page pools (wire words
     or floats for the identity codec) and ``table [B, NP]`` holds each
     sequence's page ids. Each sequence's block table gathers its pages
-    back into a contiguous ``[NP * ps, Hkv, hd]`` cache, and
+    back into a contiguous ``[Hkv, NP * ps, hd]`` cache, and
     :func:`attention_ref` — exactly the contiguous decode-then-attend
     oracle — runs per sequence (vmapped) with that sequence's own
     ``pos``/``start`` scalar (continuous batching packs unequal-length
@@ -150,14 +150,14 @@ def paged_attention_ref(q, k_pool, v_pool, table, fmt="none", *, pos,
     """
     spec = formats.resolve(fmt)
     b = q.shape[0]
-    hkv, hd = k_pool.shape[2], k_pool.shape[3]
+    hkv, hd = k_pool.shape[1], k_pool.shape[3]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     start = (jnp.zeros((b,), jnp.int32) if start is None
              else jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,)))
 
     def one(q1, tab, p1, s1):
-        kc = k_pool[tab].reshape(-1, hkv, hd)
-        vc = v_pool[tab].reshape(-1, hkv, hd)
+        kc = k_pool[tab].swapaxes(0, 1).reshape(hkv, -1, hd)
+        vc = v_pool[tab].swapaxes(0, 1).reshape(hkv, -1, hd)
         return attention_ref(q1[None], kc[None], vc[None], spec.n, spec,
                              pos=p1, start=s1[None], window=window,
                              out_dtype=out_dtype)[0]

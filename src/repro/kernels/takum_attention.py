@@ -67,6 +67,9 @@ __all__ = ["attention_kernel_call", "paged_attention_kernel_call",
 
 DEFAULT_BK = 256     # KV-sequence tile (keys per decode-and-accumulate step)
 MASKED = -1e30       # finite mask value (matches the jnp serving oracle)
+# f32 operands stay f32 on the MXU (multi-pass), as in the f32 oracle:
+# softmax weights are not bf16-exact
+F32_DOT = jax.lax.Precision.HIGHEST
 
 
 def kv_words_to_f32(words, spec: formats.FormatSpec):
@@ -105,9 +108,9 @@ def _attn_tile(pos_ref, start_ref, q_ref, kw_ref, vw_ref, o_ref,
     @pl.when(in_band)
     def _slab():
         q = q_ref[0, 0].astype(jnp.float32)              # (rows, hd)
-        k = kv_words_to_f32(kw_ref[0, :, 0, :], spec)  # (bk, hd) f32
+        k = kv_words_to_f32(kw_ref[0, 0], spec)      # (bk, hd) f32
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=F32_DOT,
             preferred_element_type=jnp.float32) * scale  # (rows, bk)
         rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         qpos = pos + rows % tq
@@ -124,8 +127,9 @@ def _attn_tile(pos_ref, start_ref, q_ref, kw_ref, vw_ref, o_ref,
         corr = jnp.exp(m_prev - m_new)                   # (rows, 128)
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = kv_words_to_f32(vw_ref[0, :, 0, :], spec)  # (bk, hd) f32
-        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
+        v = kv_words_to_f32(vw_ref[0, 0], spec)      # (bk, hd) f32
+        pv = jnp.dot(p, v, precision=F32_DOT,
+                     preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
 
     @pl.when(kk == pl.num_programs(2) - 1)
@@ -149,7 +153,7 @@ def _kv_index(b, h, kk, pos_ref, start_ref, *, bk: int, tq: int,
         # query's window floor pos - window (strict, matching the mask)
         first = jnp.maximum((pos_ref[0] - window + 1) // bk, 0)
         idx = jnp.maximum(idx, jnp.minimum(first, last))
-    return (b, idx, h, 0)
+    return (b, h, idx, 0)
 
 
 @functools.partial(jax.jit,
@@ -159,8 +163,10 @@ def attention_kernel_call(q4, kw, vw, pos, start, *,
                           spec: formats.FormatSpec,
                           bk: int = DEFAULT_BK, tq: int, window: int = 0,
                           interpret: bool = False):
-    """q4 [B, Hkv, rows, hd] float, kw/vw [B, Tpad, Hkv, hd] wire words
-    (or floats for the identity codec) -> [B, Hkv, rows, hd] f32.
+    """q4 [B, Hkv, rows, hd] float, kw/vw [B, Hkv, Tpad, hd] wire words
+    (or floats for the identity codec) -> [B, Hkv, rows, hd] f32. The
+    cache is head-major so that a KV block's last two dimensions are
+    ``(bk, hd)``, the layout Mosaic's tiling requires.
 
     ``rows = G * tq`` with row ``r`` = (group ``r // tq``, query position
     ``pos + r % tq``); padding rows alias valid positions and are
@@ -170,11 +176,11 @@ def attention_kernel_call(q4, kw, vw, pos, start, *,
     array (zeros when no left-padding).
     """
     b, hkv, rows, hd = q4.shape
-    tpad = kw.shape[1]
+    tpad = kw.shape[2]
     assert tpad % bk == 0, (tpad, bk)
-    assert kw.shape == vw.shape == (b, tpad, hkv, hd)
+    assert kw.shape == vw.shape == (b, hkv, tpad, hd)
     nkb = tpad // bk
-    kv_spec = pl.BlockSpec((1, bk, 1, hd),
+    kv_spec = pl.BlockSpec((1, 1, bk, hd),
                            functools.partial(_kv_index, bk=bk, tq=tq,
                                              window=window))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -194,7 +200,7 @@ def attention_kernel_call(q4, kw, vw, pos, start, *,
     )
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(_attn_tile, spec=spec, bk=bk, tq=tq,
@@ -211,7 +217,7 @@ def attention_kernel_call(q4, kw, vw, pos, start, *,
 # ---------------------------------------------------------------------------
 #
 # The serving scheduler (repro.serve) stores the wire-format cache as one
-# [num_pages, page_size, Hkv, hd] pool per layer; each sequence owns a row
+# [num_pages, Hkv, page_size, hd] pool per layer; each sequence owns a row
 # of a [B, NP] *block table* mapping its kk-th KV block to a pool page.
 # The table rides in as a third scalar-prefetch operand and the KV index
 # map resolves (seq, kk) -> page id, so the grid gathers pages instead of
@@ -251,9 +257,9 @@ def _paged_attn_tile(pos_ref, start_ref, table_ref, q_ref, kw_ref, vw_ref,
     @pl.when(in_band)
     def _slab():
         q = q_ref[0, 0].astype(jnp.float32)              # (rows, hd)
-        k = kv_words_to_f32(kw_ref[0, :, 0, :], spec)    # (ps, hd) f32
+        k = kv_words_to_f32(kw_ref[0, 0], spec)      # (ps, hd) f32
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=F32_DOT,
             preferred_element_type=jnp.float32) * scale  # (rows, ps)
         kpos = kk * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         msk = kpos <= pos
@@ -268,8 +274,9 @@ def _paged_attn_tile(pos_ref, start_ref, table_ref, q_ref, kw_ref, vw_ref,
         corr = jnp.exp(m_prev - m_new)                   # (rows, 128)
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = kv_words_to_f32(vw_ref[0, :, 0, :], spec)    # (ps, hd) f32
-        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
+        v = kv_words_to_f32(vw_ref[0, 0], spec)      # (ps, hd) f32
+        pv = jnp.dot(p, v, precision=F32_DOT,
+                     preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
 
     @pl.when(kk == pl.num_programs(2) - 1)
@@ -293,7 +300,7 @@ def _paged_kv_index(b, h, kk, pos_ref, start_ref, table_ref, *, ps: int,
     if window:
         first = jnp.maximum((pos_ref[b] - window + 1) // ps, 0)
         idx = jnp.maximum(idx, jnp.minimum(first, last))
-    return (table_ref[b, idx], 0, h, 0)
+    return (table_ref[b, idx], h, 0, 0)
 
 
 @functools.partial(jax.jit,
@@ -301,9 +308,10 @@ def _paged_kv_index(b, h, kk, pos_ref, start_ref, table_ref, *, ps: int,
 def paged_attention_kernel_call(q4, kw, vw, pos, start, table, *,
                                 spec: formats.FormatSpec, ps: int,
                                 window: int = 0, interpret: bool = False):
-    """q4 [B, Hkv, rows, hd] float, kw/vw [P, ps, Hkv, hd] pooled wire
+    """q4 [B, Hkv, rows, hd] float, kw/vw [P, Hkv, ps, hd] pooled wire
     words (or floats for the identity codec), table [B, NP] int32 page
-    ids -> [B, Hkv, rows, hd] f32.
+    ids -> [B, Hkv, rows, hd] f32. Pages are head-major, so a KV block's
+    last two dimensions are ``(ps, hd)``.
 
     Decode-step shape: ``rows`` is the (padded) GQA group width — every
     row of (b, h) is the same query position ``pos[b]``; padding rows
@@ -323,14 +331,14 @@ def paged_attention_kernel_call(q4, kw, vw, pos, start, table, *,
     never from a model config, which is what makes that slicing safe.
     """
     b, hkv, rows, hd = q4.shape
-    assert hkv >= 1 and q4.shape[1] == kw.shape[2], \
+    assert hkv >= 1 and q4.shape[1] == kw.shape[1], \
         (q4.shape, kw.shape)  # local (possibly sharded) head counts agree
     num_pages = kw.shape[0]
-    assert kw.shape == vw.shape == (num_pages, ps, hkv, hd), \
+    assert kw.shape == vw.shape == (num_pages, hkv, ps, hd), \
         (kw.shape, vw.shape)
     npg = table.shape[1]
     assert table.shape == (b, npg)
-    kv_spec = pl.BlockSpec((1, ps, 1, hd),
+    kv_spec = pl.BlockSpec((1, 1, ps, hd),
                            functools.partial(_paged_kv_index, ps=ps,
                                              npg=npg, window=window))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -350,7 +358,7 @@ def paged_attention_kernel_call(q4, kw, vw, pos, start, table, *,
     )
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(_paged_attn_tile, spec=spec, ps=ps,

@@ -54,6 +54,9 @@ __all__ = ["qmatmul_kernel_call", "DEFAULT_ACC_BUDGET"]
 
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 128, 128
 DEFAULT_ACC_BUDGET = 4 * 1024 * 1024  # VMEM bytes for the (M, bn) stripe
+# decoded takum16/posit16 weights are not bf16-exact: keep the MXU at f32
+# (multi-pass), matching the f32 oracle ``kernels/ref.qmatmul_ref``
+F32_DOT = jax.lax.Precision.HIGHEST
 
 
 def _qmm_ws_tile(x_ref, w_ref, o_ref, wdec_ref, *,
@@ -67,7 +70,7 @@ def _qmm_ws_tile(x_ref, w_ref, o_ref, wdec_ref, *,
         wdec_ref[...] = spec.decode_tile(w_ref[...], dtype=jnp.float32)
 
     part = jnp.dot(
-        x_ref[...].astype(jnp.float32), wdec_ref[...],
+        x_ref[...].astype(jnp.float32), wdec_ref[...], precision=F32_DOT,
         preferred_element_type=jnp.float32,
     )
     # o_ref is the whole (M, bn) stripe of column j: constant block index
@@ -94,7 +97,7 @@ def _qmm_tile_moutermost(x_ref, w_ref, o_ref, *,
 
     w = spec.decode_tile(w_ref[...], dtype=jnp.float32)
     o_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.float32), w,
+        x_ref[...].astype(jnp.float32), w, precision=F32_DOT,
         preferred_element_type=jnp.float32,
     )
 
@@ -118,7 +121,7 @@ def qmatmul_kernel_call(x, w_words, spec: formats.FormatSpec, *,
     if m * bn * 4 <= acc_budget_bytes:
         grid = (nn // bn, k // bk, m // bm)  # (j, kk, i): M innermost
         if not interpret:
-            kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+            kwargs["compiler_params"] = pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"))
         return pl.pallas_call(
             functools.partial(_qmm_ws_tile, spec=spec, bm=bm),
@@ -136,7 +139,7 @@ def qmatmul_kernel_call(x, w_words, spec: formats.FormatSpec, *,
 
     grid = (m // bm, nn // bn, k // bk)  # fallback: (i, j, kk), K innermost
     if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(_qmm_tile_moutermost, spec=spec),

@@ -317,7 +317,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         mesh = make_production_mesh(multi_pod=multi_pod)
         fn, args = build_cell(arch, shape_name, mesh, dp_mode=dp_mode,
                               runtime=runtime, overrides=overrides)
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn).lower(*args)
             t1 = time.time()
             compiled = lowered.compile()

@@ -12,7 +12,9 @@ shell half is ``launch/run.sh``, which sources the same policy):
   maps to ``--xla_force_host_platform_device_count=N`` for CPU-mesh
   experiments, mirroring ``launch/dryrun.py``'s hard-coded 512;
 * log hygiene (``TF_CPP_MIN_LOG_LEVEL``) and the large-alloc report
-  threshold so numpy staging buffers don't spam the console.
+  threshold so numpy staging buffers don't spam the console;
+* the persistent compilation cache (:func:`enable_compile_cache`),
+  turned on by the entry points, never at import.
 """
 
 from __future__ import annotations
@@ -22,7 +24,8 @@ from typing import Callable, Dict, Optional
 
 __all__ = ["TCMALLOC_PATHS", "find_tcmalloc", "tcmalloc_active",
            "host_env", "warn_if_no_tcmalloc", "KNOBS", "effective_knobs",
-           "audit_line", "log_config"]
+           "audit_line", "log_config", "enable_compile_cache",
+           "COMPILE_CACHE_DIR"]
 
 TCMALLOC_PATHS = (
     "/usr/lib/x86_64-linux-gnu/libtcmalloc.so.4",
@@ -31,6 +34,12 @@ TCMALLOC_PATHS = (
 )
 
 LARGE_ALLOC_THRESHOLD = 60_000_000_000  # quiet numpy staging buffers
+
+# fixed cache path at the checkout root: the path is part of the cache
+# key, so a temporary or per-run directory would never hit
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
 def find_tcmalloc() -> Optional[str]:
@@ -71,6 +80,24 @@ def host_env(host_device_count: Optional[int] = None,
     return env
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is: JAX reads it
+    itself and nothing else is set here. Otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR` (``.jax_cache/`` at the checkout root), so
+    a second run from the same checkout reuses the first run's
+    executables. Called by the launchers (``chip_smoke.py``,
+    ``launch/serve.py``, ``benchmarks/run.py``); tests leave it off.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
 def warn_if_no_tcmalloc(print_fn: Callable[[str], None] = print) -> bool:
     """Warn (once per call) when benchmarking without tcmalloc.
 
@@ -102,7 +129,7 @@ KNOBS: Dict[str, str] = {
     "REPRO_KV_ATTN_KERNEL": "auto",   # fused-attention dispatch (0/1/auto)
     "REPRO_AUTOTUNE": "1",            # block autotuner (0/1/force)
     "REPRO_AUTOTUNE_CACHE": "",       # sweep cache path ("" = ./.repro_autotune.json)
-    "REPRO_LUT_DECODE": "",           # LUT decode override ("" = per-format auto)
+    "REPRO_LUT_DECODE": "",           # 1 = posit8 table decode (default off)
     "REPRO_CAUSAL_SKIP": "0",         # skip fully-masked KV tiles
     "REPRO_FAULT_RATE": "0",          # injected faults per scheduler tick
     "REPRO_FAULT_SEED": "0",          # fault injector PRNG seed

@@ -21,7 +21,10 @@ __all__ = ["make_production_mesh", "dp_axes", "batch_spec_axes"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the layers constrain shardings through
+    # with_sharding_constraint, which refuses Explicit mesh axes
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple:

@@ -12,7 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
-from repro.launch.env import log_config
+from repro.launch.env import enable_compile_cache, log_config
 from repro.models import model
 from repro.obs import enabled as obs_enabled
 from repro.serve.engine import ServeEngine, quantize_weights
@@ -27,7 +27,8 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--kv-quant", default="none")
     ap.add_argument("--weights", default="none",
-                    help="'takum8'/'takum16' weight-only quantisation")
+                    help="wire format of the projection weights "
+                    "('takum8', 'takum16', ...): HBM holds the words")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="write a Perfetto-loadable Chrome trace of the "
@@ -35,6 +36,7 @@ def main():
     args = ap.parse_args()
 
     log_config()
+    enable_compile_cache()
     if args.trace and not obs_enabled():
         ap.error("--trace needs REPRO_OBS=1 (or 2) in the environment")
 
@@ -45,7 +47,7 @@ def main():
 
     params = model.init(jax.random.PRNGKey(0), cfg)
     if args.weights != "none":
-        params = quantize_weights(params, args.weights)
+        params = quantize_weights(params, args.weights, mode="wire")
 
     rng = np.random.default_rng(0)
     prompts = [list(rng.integers(0, cfg.vocab, args.prompt_len))

@@ -131,8 +131,8 @@ def forward(params, tokens, cfg: ModelConfig, *, media=None,
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
     dtype = DTYPES[cfg.dtype] if dtype is None else dtype
     one = {"attn": {
-        "k": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.hd), dtype),
-        "v": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.hd), dtype),
+        "k": jnp.zeros((batch, cfg.n_kv_heads, max_len, cfg.hd), dtype),
+        "v": jnp.zeros((batch, cfg.n_kv_heads, max_len, cfg.hd), dtype),
         "pos": jnp.zeros((), jnp.int32)}}
     return jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x, (cfg.n_layers,) + x.shape).copy(), one)

@@ -14,7 +14,7 @@ stage of the matmul unit.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, NamedTuple, Optional
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,11 +74,6 @@ def attn_init(key, d_model, n_heads, n_kv_heads, head_dim,
                          scale=(1.0 / (n_heads * head_dim)) ** 0.5,
                          dtype=dtype),
     }
-
-
-class KVChunk(NamedTuple):
-    k: jnp.ndarray  # [B, T, Hkv, hd]
-    v: jnp.ndarray
 
 
 def _proj_qkv(params, x, xa, n_heads, n_kv_heads, head_dim, rope_base,
@@ -196,12 +191,14 @@ def causal_mask(tq, tk, offset=0, window=0):
 
 
 def _encode_kv(k, v, cache, kv_quant: str):
-    """Encode fresh k/v to the cache's wire format: (spec, kw, vw).
+    """Encode fresh k/v ``[B, T, Hkv, hd]`` to the cache's head-major
+    wire format: (spec, kw, vw) with kw/vw ``[B, Hkv, T, hd]``.
 
     One registry lookup; the identity codec casts to the cache dtype,
     wire codecs encode through their ``FormatSpec.encode_tile``."""
     from repro import formats
     spec = formats.resolve(kv_quant)
+    k, v = k.swapaxes(1, 2), v.swapaxes(1, 2)
     if spec.is_identity:
         return spec, k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
     return spec, spec.encode_tile(k.astype(jnp.float32)), \
@@ -220,8 +217,8 @@ def attention(params, x, cfg, positions, *, xa=None, mask=None,
               bidirectional: bool = False, prefill_fresh: bool = False):
     """Self- or cross-attention with optional decode cache.
 
-    cache (self-attn decode): {"k","v": [B, Tmax, Hkv, hd], "pos": scalar},
-    or the paged form {"k","v": [P, ps, Hkv, hd], "table": [B, NP],
+    cache (self-attn decode): {"k","v": [B, Hkv, Tmax, hd], "pos": scalar},
+    or the paged form {"k","v": [P, Hkv, ps, hd], "table": [B, NP],
     "pos"/"start": [B]} built by ``transformer.init_paged_cache``.
     Returns (out, new_cache).
     """
@@ -237,13 +234,13 @@ def attention(params, x, cfg, positions, *, xa=None, mask=None,
         # path would materialise [Tq, Tk] scores (tens of GB at 32k)
         pos = cache["pos"]
         _, kw, vw = _encode_kv(k, v, cache, cfg.kv_quant)
-        ck = jax.lax.dynamic_update_slice(cache["k"], kw, (0, pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], vw, (0, pos, 0, 0))
+        ck = jax.lax.dynamic_update_slice(cache["k"], kw, (0, 0, pos, 0))
+        cv = jax.lax.dynamic_update_slice(cache["v"], vw, (0, 0, pos, 0))
         new_cache = {"k": ck, "v": cv, "pos": pos + x.shape[1]}
         out = _sdpa_chunked(q, k, v, window=window, causal_skip=CAUSAL_SKIP,
                             causal=True)
     elif cache is not None and xa is None and "table" in cache:
-        # paged decode (serve.paged): the cache is a [P, ps, Hkv, hd]
+        # paged decode (serve.paged): the cache is a [P, Hkv, ps, hd]
         # page pool shared by the batch, "table" [B, NP] maps each
         # sequence's KV block to a pool page, and "pos"/"start" are
         # per-sequence vectors (continuous batching packs unequal
@@ -258,15 +255,15 @@ def attention(params, x, cfg, positions, *, xa=None, mask=None,
                 "into pages by the scheduler")
         pos = cache["pos"]                                       # (B,)
         spec, kw, vw = _encode_kv(k, v, cache, cfg.kv_quant)
-        ps = cache["k"].shape[1]
+        ps = cache["k"].shape[2]
         # clamp the block index to the table width: idle scheduler
         # slots keep stepping with a stale pos and must stay in-table
         # (they point at the reserved scratch page)
         pidx = jnp.minimum(pos // ps, cache["table"].shape[1] - 1)
         page = jnp.take_along_axis(cache["table"], pidx[:, None], 1)[:, 0]
         off = pos % ps
-        ck = cache["k"].at[page, off].set(kw[:, 0])
-        cv = cache["v"].at[page, off].set(vw[:, 0])
+        ck = cache["k"].at[page, :, off].set(kw[:, :, 0])
+        cv = cache["v"].at[page, :, off].set(vw[:, :, 0])
         new_cache = dict(cache, k=ck, v=cv, pos=pos + 1)
         from repro.kernels import ops as kops
         out = kops.paged_attention(
@@ -278,13 +275,13 @@ def attention(params, x, cfg, positions, *, xa=None, mask=None,
         # then attend straight over the wire-format cache through
         # ops.takum_attention — words are decoded tile-by-tile inside the
         # fused flash kernel (or by its decode-then-attend jnp oracle
-        # off-TPU), so the full-precision [B, Tmax, Hkv, hd] K/V never
+        # off-TPU), so the full-precision [B, Hkv, Tmax, hd] K/V never
         # exist in HBM. The uncompressed cache rides the same op with
         # fmt="none" (identity encoding).
         pos = cache["pos"]
         spec, kw, vw = _encode_kv(k, v, cache, cfg.kv_quant)
-        ck = jax.lax.dynamic_update_slice(cache["k"], kw, (0, pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], vw, (0, pos, 0, 0))
+        ck = jax.lax.dynamic_update_slice(cache["k"], kw, (0, 0, pos, 0))
+        cv = jax.lax.dynamic_update_slice(cache["v"], vw, (0, 0, pos, 0))
         new_cache = {"k": ck, "v": cv, "pos": pos + x.shape[1]}
         start = cache.get("start")
         if start is not None:

@@ -309,10 +309,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
             c = {}
             for i, kind in enumerate(pat):
                 if kind in ("self", "moe"):
+                    # head-major [B, Hkv, T, hd]: a KV tile of one head
+                    # is a contiguous (T, hd) slab for the fused kernel
                     attn = {
-                        "k": jnp.zeros((batch, max_len, cfg.n_kv_heads,
+                        "k": jnp.zeros((batch, cfg.n_kv_heads, max_len,
                                         cfg.hd), kv_dtype),
-                        "v": jnp.zeros((batch, max_len, cfg.n_kv_heads,
+                        "v": jnp.zeros((batch, cfg.n_kv_heads, max_len,
                                         cfg.hd), kv_dtype),
                         "pos": jnp.zeros((), jnp.int32),
                     }
@@ -359,7 +361,7 @@ def paged_supported(cfg: ModelConfig) -> bool:
 
 def init_paged_cache(cfg: ModelConfig, batch: int, num_pages: int,
                      page_size: int, max_pages: int, dtype=None) -> list:
-    """Paged decode cache: one ``[num_pages, page_size, Hkv, hd]``
+    """Paged decode cache: one ``[num_pages, Hkv, page_size, hd]``
     wire-word pool per layer (stacked per scan group, like
     :func:`init_cache`) plus per-sequence block tables.
 
@@ -387,9 +389,9 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_pages: int,
             c = {}
             for i, _kind in enumerate(pat):
                 c[f"b{i}"] = {"attn": {
-                    "k": jnp.zeros((num_pages, page_size, cfg.n_kv_heads,
+                    "k": jnp.zeros((num_pages, cfg.n_kv_heads, page_size,
                                     cfg.hd), kv_dtype),
-                    "v": jnp.zeros((num_pages, page_size, cfg.n_kv_heads,
+                    "v": jnp.zeros((num_pages, cfg.n_kv_heads, page_size,
                                     cfg.hd), kv_dtype),
                     "table": jnp.zeros((batch, max_pages), jnp.int32),
                     "pos": jnp.zeros((batch,), jnp.int32),

@@ -147,11 +147,11 @@ class FaultInjector:
         nodes = list(pool._attn_nodes(pool.cache))
         node = int(rng.integers(len(nodes)))
         key = "k" if rng.integers(2) == 0 else "v"
-        arr = nodes[node][key]          # (n_rep, num_pages, ps, Hkv, hd)
+        arr = nodes[node][key]          # (n_rep, num_pages, Hkv, ps, hd)
         rep = int(rng.integers(arr.shape[0]))
-        head = int(rng.integers(arr.shape[3]))
+        head = int(rng.integers(arr.shape[2]))
         elem = int(rng.integers(arr.shape[4]))
-        idx = (rep, page, pi, head, elem)
+        idx = (rep, page, head, pi, elem)
         spec = pool.spec
         if self.kind == "nar":
             word = (jnp.nan if spec.is_identity

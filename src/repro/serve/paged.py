@@ -4,7 +4,7 @@ tables over the pooled cache of ``transformer.init_paged_cache``.
 The contiguous serving cache allocates ``batch x max_len`` KV slots up
 front, so every sequence pays ``max(prompt) + max_new`` whether it uses
 them or not. The :class:`PagePool` instead owns one
-``[num_pages, page_size, Hkv, hd]`` wire-word array per layer (float for
+``[num_pages, Hkv, page_size, hd]`` wire-word array per layer (float for
 the identity codec) and hands out *pages* — ``page_size`` consecutive KV
 positions — from a free list. A sequence's pages are glued together by
 its row of the block table (``[batch_slots, max_pages]`` int32 page
@@ -89,20 +89,21 @@ def _copy_kernels():
         import jax
 
         def gather(pool_kv, dst, pages):
-            # pool pages -> head of a batch-1 contiguous cache
-            tiles = pool_kv[:, pages]           # (n_rep, npg, ps, ...)
-            n_rep, npg, ps = tiles.shape[:3]
-            span = tiles.reshape((n_rep, 1, npg * ps) + tiles.shape[3:])
-            return dst.at[:, :, :npg * ps].set(span)
+            # pool pages (n_rep, P, Hkv, ps, hd) -> head of a batch-1
+            # contiguous cache (n_rep, 1, Hkv, T, hd)
+            tiles = pool_kv[:, pages]           # (n_rep, npg, Hkv, ps, hd)
+            n_rep, npg, hkv, ps, hd = tiles.shape
+            span = tiles.swapaxes(1, 2).reshape(n_rep, 1, hkv, npg * ps, hd)
+            return dst.at[:, :, :, :npg * ps].set(span)
 
         @functools.partial(jax.jit, static_argnames=("first_page",))
         def scatter(pool_kv, src, pages, *, first_page):
             # contiguous pages [first_page, first_page+npg) -> pool pages
-            n_rep = src.shape[0]
-            ps = pool_kv.shape[2]
+            n_rep, _, hkv, _, hd = src.shape
+            ps = pool_kv.shape[3]
             npg = pages.shape[0]
-            span = src[:, 0, first_page * ps:(first_page + npg) * ps]
-            tiles = span.reshape((n_rep, npg, ps) + src.shape[3:])
+            span = src[:, 0, :, first_page * ps:(first_page + npg) * ps]
+            tiles = span.reshape(n_rep, hkv, npg, ps, hd).swapaxes(1, 2)
             return pool_kv.at[:, pages].set(tiles)
 
         _JIT_COPY["gather"] = jax.jit(gather)
@@ -417,8 +418,8 @@ class PagePool:
         for pool_attn, contig_attn in zip(self._attn_nodes(self.cache),
                                           self._attn_nodes(contig_caches)):
             for key in ("k", "v"):
-                src = contig_attn[key]          # (n_rep, 1, T, Hkv, hd)
-                b1, t = src.shape[1:3]
+                src = contig_attn[key]          # (n_rep, 1, Hkv, T, hd)
+                b1, t = src.shape[1], src.shape[3]
                 if b1 != 1 or t < need:
                     raise ValueError(
                         f"scatter_prefill expects a batch-1 contiguous "
@@ -449,8 +450,8 @@ class PagePool:
                                           self._attn_nodes(contig_caches)):
             if npg:
                 for key in ("k", "v"):
-                    dst = contig_attn[key]      # (n_rep, 1, T, Hkv, hd)
-                    if dst.shape[1] != 1 or dst.shape[2] < npg * ps:
+                    dst = contig_attn[key]      # (n_rep, 1, Hkv, T, hd)
+                    if dst.shape[1] != 1 or dst.shape[3] < npg * ps:
                         raise ValueError(
                             f"gather_prefix needs a batch-1 contiguous "
                             f"cache of at least {npg} x {ps} positions, "

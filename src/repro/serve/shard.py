@@ -243,9 +243,9 @@ def _cache_spec_for(key: str, ndim: int, plan: ShardPlan) -> P:
         # rank-major error-feedback residual: [n_rep, tp, W, 1, C]
         return P(None, plan.tensor_axis)
     if key in ("k", "v") and ndim == 5:
-        # paged pool [n_rep, P, ps, Hkv, hd] or contiguous
-        # [n_rep, B, T, Hkv, hd]: the KV head dim shards either way
-        return P(None, None, None, plan.tensor_axis, None)
+        # paged pool [n_rep, P, Hkv, ps, hd] or contiguous
+        # [n_rep, B, Hkv, T, hd]: the KV head dim shards either way
+        return P(None, None, plan.tensor_axis, None, None)
     return P()  # table / pos / start: host-global, replicated
 
 
@@ -335,7 +335,6 @@ class ShardedSteps:
 
     def prefill_chunk(self, params, tokens, cache, pos, last_idx):
         def build(pspecs, cspecs):
-            from jax.experimental.shard_map import shard_map
             cfg, ctx = self.plan.local_cfg(self.cfg), self._ctx()
 
             def local(params, tokens, cache, pos, last_idx):
@@ -343,10 +342,10 @@ class ShardedSteps:
                     return model.prefill_chunk(params, tokens, cfg, cache,
                                                pos=pos, last_idx=last_idx)
 
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(pspecs, P(), cspecs, P(), P()),
-                out_specs=(P(), cspecs), check_rep=False))
+                out_specs=(P(), cspecs), check_vma=False))
         fn = self._get("prefill", params, cache, build)
         return fn(params, tokens, cache, pos, last_idx)
 
@@ -354,7 +353,6 @@ class ShardedSteps:
         self.ensure_residuals(cache)
 
         def build(pspecs, cspecs):
-            from jax.experimental.shard_map import shard_map
             from repro.serve.engine import sample_rows
             cfg, ctx = self.plan.local_cfg(self.cfg), self._ctx()
 
@@ -367,10 +365,10 @@ class ShardedSteps:
                     bad = jnp.any(jnp.isnan(logits), axis=-1)
                     return toks[:, None], cache, new_keys, bad
 
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(pspecs, P(), cspecs, P(), P(), P(), P()),
-                out_specs=(P(), cspecs, P(), P()), check_rep=False))
+                out_specs=(P(), cspecs, P(), P()), check_vma=False))
         fn = self._get("step", params, cache, build)
         return fn(params, tok, cache, pos, keys, temps, top_ps)
 

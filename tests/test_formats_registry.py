@@ -147,8 +147,9 @@ def test_attention_kernel_matches_oracle(spec):
     b, t, hkv, g, hd = 2, 96, 2, 2, 16
     rng = np.random.default_rng(9)
     q = jnp.asarray(rng.normal(size=(b, 1, g * hkv, hd)), jnp.float32)
-    kf = rng.normal(size=(b, t, hkv, hd)).astype(np.float32)
-    vf = rng.normal(size=(b, t, hkv, hd)).astype(np.float32)
+    # head-major caches [B, Hkv, T, hd]
+    kf = rng.normal(size=(b, t, hkv, hd)).astype(np.float32).swapaxes(1, 2)
+    vf = rng.normal(size=(b, t, hkv, hd)).astype(np.float32).swapaxes(1, 2)
     kw, vw = spec.encode_tile(kf), spec.encode_tile(vf)
     got = ops.takum_attention(q, kw, vw, spec.n, spec, pos=t - 1,
                               use_kernel=True, interpret=True, block=32)
@@ -275,8 +276,8 @@ def test_kv_quant_posit8_decode_step_kernel_parity(monkeypatch):
     b, tmax, pos = 2, 48, 33
     words = spec.encode_tile(
         rng.normal(size=(b, tmax, cfg.n_kv_heads, cfg.hd))
-        .astype(np.float32))
-    cache = {"k": words, "v": words[:, ::-1],
+        .astype(np.float32).swapaxes(1, 2))
+    cache = {"k": words, "v": words[:, :, ::-1],
              "pos": jnp.asarray(pos, jnp.int32),
              "start": jnp.asarray([0, 4], jnp.int32)}
     x = jnp.asarray(rng.normal(size=(b, 1, cfg.d_model)), jnp.float32)

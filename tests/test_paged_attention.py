@@ -23,8 +23,9 @@ B = 4
 
 
 def _pool_and_table(rng, spec, *, garbage=False):
-    kf = rng.normal(size=(P, PS, HKV, HD)).astype(np.float32)
-    vf = rng.normal(size=(P, PS, HKV, HD)).astype(np.float32)
+    # head-major pages [P, Hkv, ps, hd]
+    kf = rng.normal(size=(P, PS, HKV, HD)).astype(np.float32).swapaxes(1, 2)
+    vf = rng.normal(size=(P, PS, HKV, HD)).astype(np.float32).swapaxes(1, 2)
     if spec.is_identity:
         kw, vw = jnp.asarray(kf), jnp.asarray(vf)
     else:
@@ -89,8 +90,8 @@ def test_paged_matches_contiguous_reference():
     q = _q(rng)
     got, _ = _parity(q, kw, vw, table, spec, pos=pos, start=start)
     # contiguous reference: the same pages glued in order
-    kc = kw[1:NP + 1].reshape(NP * PS, HKV, HD)[None]
-    vc = vw[1:NP + 1].reshape(NP * PS, HKV, HD)[None]
+    kc = kw[1:NP + 1].swapaxes(0, 1).reshape(HKV, NP * PS, HD)[None]
+    vc = vw[1:NP + 1].swapaxes(0, 1).reshape(HKV, NP * PS, HD)[None]
     for b in range(B):
         want = ref.attention_ref(q[b:b + 1], kc, vc, spec.n, spec,
                                  pos=int(pos[b]), start=start[b:b + 1])
@@ -112,7 +113,7 @@ def test_stale_words_on_recycled_pages_are_masked():
     # block 1 offsets 5.., blocks 2, 3) and over the whole scratch page
     tab0 = np.asarray(table)[0]
     kw2 = np.asarray(kw).copy()
-    kw2[tab0[1], 5:] = 201
+    kw2[tab0[1], :, 5:] = 201
     kw2[tab0[2:]] = 77
     kw2[0] = 123
     got = ops.paged_attention(q, jnp.asarray(kw2), vw, table, spec, pos=pos,
@@ -138,7 +139,7 @@ def test_nar_poisons_only_attending_sequence():
     kw, vw, table = _pool_and_table(rng, spec)
     nar = word_dtype(16)(takum.NAR(16))
     # NaR at seq 1's position 8 (its page table[1, 0], offset 8), kv head 0
-    kw = kw.at[int(table[1, 0]), 8, 0, 0].set(nar)
+    kw = kw.at[int(table[1, 0]), 0, 8, 0].set(nar)
     pos = jnp.asarray([NP * PS - 1, 37, 20, 0], jnp.int32)
     got = np.asarray(ops.paged_attention(_q(rng), kw, vw, table, spec,
                                          pos=pos, use_kernel=True,
@@ -177,7 +178,7 @@ def test_layers_paged_branch_appends_and_routes(monkeypatch):
     npg = 3
     words = takum.float_to_takum(
         rng.normal(size=(npages, ps, cfg.n_kv_heads, cfg.hd))
-        .astype(np.float32), 16)
+        .astype(np.float32).swapaxes(1, 2), 16)
     table = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
     pos = jnp.asarray([33, 17], jnp.int32)
     cache = {"k": words, "v": words[::-1], "table": table, "pos": pos,
@@ -194,13 +195,14 @@ def test_layers_paged_branch_appends_and_routes(monkeypatch):
         np.testing.assert_array_equal(np.asarray(newc["pos"]),
                                       np.asarray(pos) + 1)
         assert newc["k"].dtype == word_dtype(16)
-        assert newc["k"].shape == (npages, ps, cfg.n_kv_heads, cfg.hd)
+        assert newc["k"].shape == (npages, cfg.n_kv_heads, ps, cfg.hd)
         # the append hit exactly (table[b, pos // ps], pos % ps)
         for i in range(b):
             pg = int(table[i, int(pos[i]) // ps])
             off = int(pos[i]) % ps
             assert not np.array_equal(
-                np.asarray(newc["k"][pg, off]), np.asarray(words[pg, off]))
+                np.asarray(newc["k"][pg, :, off]),
+                np.asarray(words[pg, :, off]))
     np.testing.assert_allclose(outs[True], outs[False], rtol=1e-5,
                                atol=2e-5)
 
@@ -215,8 +217,8 @@ def test_layers_paged_branch_is_decode_only():
                               kv_quant="takum8")
     params = L.attn_init(jax.random.PRNGKey(0), cfg.d_model, cfg.n_heads,
                          cfg.n_kv_heads, cfg.hd)
-    cache = {"k": jnp.zeros((3, 8, cfg.n_kv_heads, cfg.hd), jnp.uint8),
-             "v": jnp.zeros((3, 8, cfg.n_kv_heads, cfg.hd), jnp.uint8),
+    cache = {"k": jnp.zeros((3, cfg.n_kv_heads, 8, cfg.hd), jnp.uint8),
+             "v": jnp.zeros((3, cfg.n_kv_heads, 8, cfg.hd), jnp.uint8),
              "table": jnp.zeros((1, 2), jnp.int32),
              "pos": jnp.zeros((1,), jnp.int32),
              "start": jnp.zeros((1,), jnp.int32)}

@@ -25,8 +25,9 @@ H = G * HKV
 
 def _cache(rng, n, fmt, t=T):
     spec = formats.resolve(fmt, n)
-    kf = rng.normal(size=(B, t, HKV, HD)).astype(np.float32)
-    vf = rng.normal(size=(B, t, HKV, HD)).astype(np.float32)
+    # head-major caches [B, Hkv, T, hd]
+    kf = rng.normal(size=(B, t, HKV, HD)).astype(np.float32).swapaxes(1, 2)
+    vf = rng.normal(size=(B, t, HKV, HD)).astype(np.float32).swapaxes(1, 2)
     if spec.is_identity:
         return jnp.asarray(kf), jnp.asarray(vf)
     return spec.encode_tile(kf), spec.encode_tile(vf)
@@ -121,7 +122,7 @@ def test_nar_words_poison_only_attending_rows():
     kw, vw = _cache(rng, 16, "linear")
     nar = word_dtype(16)(takum.NAR(16))
     # K NaR at a *valid* position of kv head 0, batch 0
-    kw = kw.at[0, 10, 0, 3].set(nar)
+    kw = kw.at[0, 0, 10, 3].set(nar)
     pos = T - 1
     got = ops.takum_attention(_q(rng), kw, vw, 16, "linear", pos=pos,
                               use_kernel=True, interpret=True, block=32)
@@ -132,7 +133,7 @@ def test_nar_words_poison_only_attending_rows():
     # a V NaR poisons exactly its head-dim component (one column of
     # p @ v), for every query row attending to its kv head
     kw2, vw2 = _cache(rng, 16, "linear")
-    vw2 = vw2.at[1, 5, 1, 0].set(nar)
+    vw2 = vw2.at[1, 1, 5, 0].set(nar)
     got2 = np.asarray(ops.takum_attention(
         _q(rng), kw2, vw2, 16, "linear", pos=pos, use_kernel=True,
         interpret=True, block=32))
@@ -144,7 +145,7 @@ def test_nar_words_poison_only_attending_rows():
 def test_nar_behind_start_mask_is_contained():
     rng = np.random.default_rng(7)
     kw, vw = _cache(rng, 16, "linear")
-    kw = kw.at[0, 2, 0, 0].set(word_dtype(16)(takum.NAR(16)))
+    kw = kw.at[0, 0, 2, 0].set(word_dtype(16)(takum.NAR(16)))
     start = jnp.asarray([5, 0], jnp.int32)  # NaR sits in masked padding
     got, _ = _parity(_q(rng), kw, vw, 16, "linear", pos=T - 1, start=start)
     assert np.isfinite(np.asarray(got)).all()
@@ -172,7 +173,7 @@ def _iter_param_avals(val):
 
 def test_kernel_path_never_materialises_full_precision_kv():
     """The acceptance property: on the fused path, no float array the
-    size of the decoded [B, Tmax, Hkv, hd] cache exists anywhere in the
+    size of the decoded [B, Hkv, Tmax, hd] cache exists anywhere in the
     jaxpr — including inside the pallas_call body, whose decodes are
     (bk, hd) tiles."""
     rng = np.random.default_rng(8)
@@ -218,8 +219,8 @@ def test_layers_decode_routes_through_fused_op(monkeypatch):
     b, tmax, pos = 2, 48, 33
     words = takum.float_to_takum(
         rng.normal(size=(b, tmax, cfg.n_kv_heads, cfg.hd))
-        .astype(np.float32), 16)
-    cache = {"k": words, "v": words[:, ::-1],
+        .astype(np.float32).swapaxes(1, 2), 16)
+    cache = {"k": words, "v": words[:, :, ::-1],
              "pos": jnp.asarray(pos, jnp.int32),
              "start": jnp.asarray([0, 4], jnp.int32)}
     x = jnp.asarray(rng.normal(size=(b, 1, cfg.d_model)), jnp.float32)
