@@ -82,15 +82,6 @@ ring-interconnect byte census per step (compression scales it by
 fewer interconnect bytes than their f32 twins, and tp=8 normalized
 throughput >= tp=1.
 
-Schema 9 additions: observability overhead rows
-(``serving_obs["obs/takum8/{off,on}"]``) — the same continuous-batching
-workload with ``REPRO_OBS`` unset and at level 1 (tracing + metrics).
-The ``on`` row records ``overhead_pct`` (best-round wall time vs the
-off row), ``token_parity`` (the on-run's tokens are bit-identical —
-observability is token-neutral by contract) and
-``recompiles_steady_state`` from the compile watcher, armed after the
-warmup round. Gates: overhead <= 5%, recompiles == 0, parity true.
-
 ``--smoke`` (also ``run(smoke=True)``) shrinks every shape to
 CI-on-CPU size and writes ``BENCH_codec.smoke.json`` instead — a schema
 and dataflow gate (every row still exercises its real code path), not a
@@ -605,114 +596,6 @@ def _sharded_serving_rows(smoke: bool) -> dict:
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def _obs_serving_rows(smoke: bool) -> dict:
-    """Observability overhead rows (schema 9): the same continuous-
-    batching workload with ``REPRO_OBS`` unset and at level 1 (tracing
-    + metrics; level 2's per-tick device sync is a diagnostic mode, not
-    a production default, so it is not priced here). The ``on`` row
-    carries ``overhead_pct`` (from the best round each — the low-noise
-    estimator; medians are reported too), ``token_parity`` (the on-run
-    generates bit-identical tokens — the contract the serve-gate suites
-    pin) and ``recompiles_steady_state`` (the compile watcher is armed
-    after the warmup round; any retrace after that is a defect). The
-    off/on rounds are *interleaved* on two live engines, so monotone
-    machine-load drift hits both sides equally instead of being billed
-    to whichever side ran second. The schema gate holds overhead at
-    <= 5% and recompiles at exactly 0."""
-    import dataclasses
-    import os
-    import statistics
-
-    import jax as _jax
-
-    from repro.configs import get_arch
-    from repro.models import model as _model
-    from repro.serve.engine import ServeEngine
-
-    base = get_arch("phi3-medium-14b").reduced
-    if smoke:
-        plens, max_new, ps, db, rounds = (4, 7, 11, 6, 9, 13), 4, 8, 2, 5
-    else:
-        plens = (73, 41, 150, 210, 30, 90, 120, 55)
-        max_new, ps, db, rounds = 64, 64, 4, 3
-    rng = np.random.default_rng(2)
-    prompts = [list(rng.integers(0, base.vocab, n)) for n in plens]
-    cfg = dataclasses.replace(base, kv_quant="takum8")
-    params = _model.init(_jax.random.PRNGKey(0), base)
-
-    out: dict = {}
-    results: dict = {}
-    prior = os.environ.get("REPRO_OBS")
-
-    def _set_env(obs_on):
-        if obs_on:
-            os.environ["REPRO_OBS"] = "1"
-        else:
-            os.environ.pop("REPRO_OBS", None)
-
-    def _round(eng):
-        t0 = time.perf_counter()
-        rids = [eng.submit(p, max_new) for p in prompts]
-        n_tokens = 0
-        for ev in eng.run():
-            n_tokens += ev.token >= 0
-        return time.perf_counter() - t0, n_tokens, rids
-
-    try:
-        # prefix cache off: every round redoes the same work, so round
-        # times are comparable and the delta is pure obs cost
-        engines, totals, tps = {}, {}, {}
-        for obs_on in (False, True):
-            _set_env(obs_on)
-            engines[obs_on] = ServeEngine(
-                params, cfg, max_len=max(plens) + max_new,
-                page_size=ps, decode_batch=db, prefix_cache=False)
-            _round(engines[obs_on])   # warmup: compiles + first traces
-            if engines[obs_on].obs is not None:
-                engines[obs_on].obs.arm_steady()
-            totals[obs_on], tps[obs_on] = [], []
-        for _ in range(rounds):
-            for obs_on in (False, True):
-                _set_env(obs_on)
-                dt, n_tokens, rids = _round(engines[obs_on])
-                totals[obs_on].append(dt)
-                tps[obs_on].append(n_tokens / dt)
-                results[obs_on] = [engines[obs_on].result(r)
-                                   for r in rids]
-        for obs_on in (False, True):
-            eng = engines[obs_on]
-            key = f"obs/takum8/{'on' if obs_on else 'off'}"
-            out[key] = {
-                "repro_obs": "1" if obs_on else "(unset)",
-                "n_requests": len(prompts),
-                "max_new": max_new,
-                "page_size": ps,
-                "decode_batch": db,
-                "timed_rounds": rounds,
-                "us": round(statistics.median(totals[obs_on]) * 1e6, 2),
-                "us_best": round(min(totals[obs_on]) * 1e6, 2),
-                "tokens_per_s": round(statistics.median(tps[obs_on]), 2),
-                "path": "scheduler",
-            }
-            if eng.obs is not None:
-                w = eng.obs.compile_watcher
-                out[key]["recompiles_steady_state"] = \
-                    w.steady_state_recompiles
-                out[key]["compiles_total"] = w.compiles
-                out[key]["trace_spans"] = len(eng.obs.tracer.spans)
-                eng.obs.close()
-    finally:
-        if prior is None:
-            os.environ.pop("REPRO_OBS", None)
-        else:
-            os.environ["REPRO_OBS"] = prior
-    on, off = out["obs/takum8/on"], out["obs/takum8/off"]
-    on["token_parity"] = results[True] == results[False]
-    on["overhead_pct"] = round(
-        100.0 * (on["us_best"] - off["us_best"]) / off["us_best"], 2)
-    return out
-
-
 def run(print_fn=print, out_path: str | None = None,
         smoke: bool = False) -> dict:
     from benchmarks import roofline
@@ -744,7 +627,6 @@ def run(print_fn=print, out_path: str | None = None,
                     **_prefix_serving_rows(smoke)},
         "serving_faults": _faults_serving_rows(smoke),
         "serving_sharded": _sharded_serving_rows(smoke),
-        "serving_obs": _obs_serving_rows(smoke),
     }
     doc["roofline"] = roofline.kernel_points_from_bench(doc)
     with open(out_path, "w") as f:
@@ -790,15 +672,6 @@ def run(print_fn=print, out_path: str | None = None,
             f"tokens_per_s={row['tokens_per_s']} "
             f"interconnect_bytes_per_step="
             f"{row['interconnect_bytes_per_step']}"))
-    for key, row in doc["serving_obs"].items():
-        extra = f"tokens_per_s={row['tokens_per_s']}"
-        if "overhead_pct" in row:
-            extra += (f" overhead_pct={row['overhead_pct']} "
-                      f"recompiles_steady_state="
-                      f"{row['recompiles_steady_state']} "
-                      f"token_parity={row['token_parity']}")
-        print_fn(csv_line(f"codec_json/serving_obs/{key}", row["us"],
-                          extra))
     print_fn(f"# wrote {out_path}")
     return doc
 

@@ -36,6 +36,12 @@ check; ``1`` — tracing + metrics; ``2`` — tracing + metrics + numeric
 health. The scheduler builds its bundle via :func:`obs_from_env` at
 construction, on its own injectable clock, so traces from tests on a
 fake clock are deterministic.
+
+The scheduler's phases (:func:`phase`) are the one exception to the
+gate: at every level each phase is also a ``jax.profiler``
+annotation ``sched.<name>``, which costs a check when no profiler
+session records and puts the host's phases on the device trace's clock
+when one does.
 """
 
 from __future__ import annotations
@@ -45,11 +51,11 @@ from typing import Callable, Optional
 
 from repro.obs import export, metrics, trace  # noqa: F401 (re-export)
 from repro.obs.metrics import GLOBAL, CompileWatcher, MetricsRegistry
-from repro.obs.trace import SCHED_TRACK, RequestTiming, Tracer
+from repro.obs.trace import SCHED_TRACK, RequestTiming, Tracer, phase
 
 __all__ = ["OBS_ENV", "level", "enabled", "numeric_enabled", "ServeObs",
            "obs_from_env", "Tracer", "MetricsRegistry", "CompileWatcher",
-           "RequestTiming", "SCHED_TRACK", "GLOBAL"]
+           "RequestTiming", "SCHED_TRACK", "GLOBAL", "phase"]
 
 OBS_ENV = "REPRO_OBS"
 

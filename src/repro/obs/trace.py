@@ -2,12 +2,13 @@
 
 A trace is a flat list of :class:`Span` / :class:`Instant` records on
 integer *tracks*. Track ``rid`` carries one request's lifecycle; track
-:data:`SCHED_TRACK` (-1) carries scheduler-wide events (ticks, fault
-injections before they hit a specific request). Within a track, spans
-are **well-nested by construction**: :meth:`Tracer.begin` pushes onto a
-per-track stack and :meth:`Tracer.end` pops it, so a child can never
-outlive its parent — the property the fuzz harness asserts for every
-terminal request. Time comes from an injectable ``now_fn`` (the same
+:data:`SCHED_TRACK` (-1) carries scheduler-wide events (ticks, the
+scheduler's phases, fault injections before they hit a specific
+request); :func:`phase` records a phase here and, as ``sched.<name>``,
+in the profiler trace. Within a track, spans are **well-nested by
+construction**: :meth:`Tracer.begin` pushes onto a per-track stack and
+:meth:`Tracer.end` pops it, so a child can never outlive its parent —
+the property the fuzz harness asserts for every terminal request. Time comes from an injectable ``now_fn`` (the same
 clock the scheduler runs on), so traces recorded under a test
 ``FakeClock`` are deterministic.
 
@@ -25,16 +26,20 @@ are always on (they're three float stores per token), so terminal
 
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
-__all__ = ["SCHED_TRACK", "Span", "Instant", "Tracer", "RequestTiming",
-           "percentile"]
+__all__ = ["SCHED_TRACK", "PHASE_PREFIX", "Span", "Instant", "Tracer",
+           "RequestTiming", "percentile", "phase"]
 
 #: Track id for scheduler-wide (non-request) events.
 SCHED_TRACK = -1
+
+#: Prefix of every scheduler phase's span name in the profiler trace.
+PHASE_PREFIX = "sched."
 
 
 @dataclass
@@ -150,6 +155,65 @@ class Tracer:
         recs.sort(key=lambda r: (r.get("t0", r.get("t", 0.0)),
                                  r.get("depth", 0)))
         return recs
+
+
+_TRACE_ME = None     # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _trace_me():
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        from jax.profiler import TraceAnnotation  # local: import-cheap obs
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME
+
+
+class _Phase:
+    """One scheduler phase in both sinks: a profiler annotation named
+    ``sched.<name>`` while a profiler session records host events, and
+    the span ``<name>`` on :data:`SCHED_TRACK` when a tracer is given."""
+
+    __slots__ = ("_name", "_tracer", "_args", "_ann")
+
+    def __init__(self, name: str, tracer: Optional[Tracer],
+                 args: Dict[str, Any], profiling: bool):
+        self._name = name
+        self._tracer = tracer
+        self._args = args
+        self._ann = (_trace_me()(PHASE_PREFIX + name, **args)
+                     if profiling else None)
+
+    def __enter__(self) -> "_Phase":
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tracer is not None:
+            self._tracer.begin(SCHED_TRACK, self._name, **self._args)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._tracer is not None:
+            self._tracer.end(SCHED_TRACK, self._name)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+_OFF = contextlib.nullcontext()
+
+
+def phase(name: str, tracer: Optional[Tracer] = None, **args: Any):
+    """Context manager for one scheduler phase, the single entry for both
+    of its sinks: the profiler trace (``sched.<name>``, on the device
+    trace's clock, whatever ``REPRO_OBS`` says) and ``tracer`` (the span
+    ``<name>`` on :data:`SCHED_TRACK`, on the scheduler clock). With no
+    profiler session recording and no tracer it returns a shared no-op
+    context: no name is built and no annotation is made. A phase must
+    close before the scheduler yields, so a stream consumer's time lies
+    outside every phase."""
+    profiling = _trace_me().is_enabled()
+    if tracer is None and not profiling:
+        return _OFF
+    return _Phase(name, tracer, args, profiling)
 
 
 def percentile(xs: Sequence[float], q: float) -> float:

@@ -123,6 +123,11 @@ class PageStats:
     shared_pages: int       # pages with refcount > 1 (prefix dedup)
     prefix_hit_tokens: int  # prompt tokens served from shared pages
     quarantined: int        # corrupted pages retired from circulation
+    # summed over decode steps and their active rows (``advance``): KV
+    # positions written, and the positions of the pages the rows hold
+    # (a shared page counts once per row); their ratio is the pool's fill
+    fill_tokens: int
+    fill_capacity: int
 
 
 class PagePool:
@@ -157,6 +162,10 @@ class PagePool:
         self._quarantined: set = set()
         self._peak = 0
         self._prefix_hit_tokens = 0
+        self._fill_tokens = 0
+        self._fill_capacity = 0
+        # pages given to each slot by assign (0 for an idle slot)
+        self._slot_pages = [0] * batch
         # host mirrors of the per-slot table state (pushed on change)
         self.table = np.zeros((batch, max_pages), np.int32)
         self.pos = np.zeros((batch,), np.int32)
@@ -338,7 +347,9 @@ class PagePool:
                          hbm_bytes=self.hbm_bytes(),
                          shared_pages=self.shared_pages(),
                          prefix_hit_tokens=self._prefix_hit_tokens,
-                         quarantined=self.pages_quarantined())
+                         quarantined=self.pages_quarantined(),
+                         fill_tokens=self._fill_tokens,
+                         fill_capacity=self._fill_capacity)
 
     # -- block tables ------------------------------------------------------
 
@@ -350,20 +361,29 @@ class PagePool:
         self.table[slot, :len(pages)] = pages
         self.pos[slot] = pos
         self.start[slot] = start
+        self._slot_pages[slot] = len(pages)
 
     def clear(self, slot: int) -> None:
         """Idle a slot: scratch-page table row, pos/start 0."""
         self.table[slot] = 0
         self.pos[slot] = 0
         self.start[slot] = 0
+        self._slot_pages[slot] = 0
 
     def advance(self, slots: Sequence[int]) -> None:
         """Mirror one compiled decode step: the device cache advanced
         every slot's ``pos`` by 1; track the active ones here (idle
         slots drift on device — harmless, see the kernel's table
-        clamp — and are resynced by the next :meth:`push_tables`)."""
+        clamp — and are resynced by the next :meth:`push_tables`).
+        Adds the active rows' written positions and held capacity to
+        the fill counters (``PageStats.fill_tokens``/``fill_capacity``)."""
+        tokens = pages = 0
         for s in slots:
             self.pos[s] += 1
+            tokens += int(self.pos[s])
+            pages += self._slot_pages[s]
+        self._fill_tokens += tokens
+        self._fill_capacity += pages * self.page_size
 
     # -- device-cache plumbing --------------------------------------------
 

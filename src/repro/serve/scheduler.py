@@ -95,7 +95,7 @@ import numpy as np
 
 from repro.ft.watchdog import Heartbeat, Watchdog
 from repro.obs import obs_from_env
-from repro.obs.trace import SCHED_TRACK, RequestTiming
+from repro.obs.trace import SCHED_TRACK, RequestTiming, phase
 from repro.serve.faults import injector_from_env
 from repro.serve.paged import AdmissionError, PagePool, pages_for
 from repro.serve.prefix import PrefixCache, PrefixPlan
@@ -267,6 +267,8 @@ class Scheduler:
         # scheduler clock. Every hook below is a None-check — obs must
         # be token-neutral AND near-free when off.
         self.obs = obs_from_env(self._now)
+        # the phases' second sink (repro.obs.phase): None when obs is off
+        self._tracer = None if self.obs is None else self.obs.tracer
         self.watchdog = Watchdog(
             1, dead_after=stall_after, now_fn=self._now,
             on_transition=None if self.obs is None else self._obs_host)
@@ -450,12 +452,14 @@ class Scheduler:
                 if inj is not None and getattr(inj, "observer", 1) is None:
                     inj.observer = self._obs_fault
                 obs.tracer.begin(SCHED_TRACK, "tick", tick=self._tick)
-            self._heartbeat()
-            if self.injector is not None:
-                self.injector.step(self._tick)
-            self._check_deadlines()
+            with phase("housekeeping", self._tracer):
+                self._heartbeat()
+                if self.injector is not None:
+                    self.injector.step(self._tick)
+                self._check_deadlines()
             yield from self._drain_pending()
-            self._admit()
+            with phase("admit", self._tracer, queued=len(self._queue)):
+                self._admit()
             yield from self._prefill_tick()
             yield from self._decode_step()
             yield from self._drain_pending()
@@ -818,78 +822,103 @@ class Scheduler:
         committed the new device state: a consumer that abandons the
         stream mid-yield must never leave host bookkeeping ahead of the
         device cache."""
-        import jax.numpy as jnp
-        eng = self.engine
-        ps = self.page_size
         events = []
-        activated = False
         for slot in range(self.decode_batch):
             req = self._slots[slot]
             if req is None or req.state != "prefilling":
                 continue
             stream = req.prefill_tokens
-            plen = len(stream)
-            chunk = stream[req._cursor:req._cursor + ps]
-            tokens = np.zeros((1, ps), np.int32)
-            tokens[0, :len(chunk)] = chunk
-            if self.obs is not None:
-                self.obs.tracer.begin(req.rid, "chunk",
-                                      pos=req._cursor, n=len(chunk))
-            row, req._contig = eng._prefill_chunk(
-                eng.params, jnp.asarray(tokens), req._contig,
-                jnp.asarray(req._cursor, jnp.int32),
-                jnp.asarray(len(chunk) - 1, jnp.int32))
-            req._cursor += len(chunk)
-            if self.obs is not None:
-                self.obs.tracer.end(req.rid, "chunk")
-            if req._cursor < plen:
+            chunk = stream[req._cursor:req._cursor + self.page_size]
+            with phase("prefill_chunk", self._tracer, pos=req._cursor,
+                       n=len(chunk)):
+                row = self._dispatch_chunk(req, chunk)
+            if req._cursor < len(stream):
                 continue
-            if bool(np.isnan(np.asarray(row)).any()):
+            with phase("prefill_sync", self._tracer):
+                tok0 = self._sample_prefilled(req, row)
+            if tok0 is None:
                 # corrupted wire words reached these logits: NaR decode
                 # pins corruption -> NaN, so this request is poisoned
                 self._poison(req)
                 continue
-            # prefill complete: sample the next token under the policy
-            if req.temperature > 0.0:
-                keys = self._request_key(req)[None]
-            else:
-                keys = jnp.zeros((1, 2), jnp.uint32)
-            toks, new_keys = eng._sample_rows(
-                row, keys, jnp.asarray([req.temperature], jnp.float32),
-                jnp.asarray([req.top_p], jnp.float32))
-            if req.temperature > 0.0:
-                req._key = new_keys[0]
-            tok0 = int(np.asarray(toks)[0])
-            n_prompt_pages = pages_for(plen, ps)
-            self.pool.scatter_prefill(
-                req._contig, req.pages[req._first_page:n_prompt_pages],
-                first_page=req._first_page)
-            req._contig = None
-            if self.prefix is not None:
-                self.prefix.insert(stream, req.pages[:plen // ps])
-            req.state = "active"
-            req.generated.append(tok0)
-            now = self._now()
-            req.t_first = now
-            req.tok_times.append(now)
-            self.pool.assign(slot, req.pages, pos=plen)
-            activated = True
-            if self.obs is not None:
-                tr = self.obs.tracer
-                tr.end(req.rid, "prefill", t=now)
-                tr.instant(req.rid, "first_token", t=now, token=tok0)
-                tr.begin(req.rid, "decode", t=now)
-                self.obs.metrics.counter("sched.tokens").inc()
-            done = tok0 == req.eos_id or len(req.generated) >= req.max_new
-            tm = None
-            if done:
-                self._release(req)
-                tm = self._finish(req)
-            events.append(StreamEvent(req.rid, tok0, done,
-                                      t=now, timing=tm))
-        if activated:
-            self.pool.push_tables()
+            with phase("prefill_commit", self._tracer):
+                events.append(self._activate(slot, req, stream, tok0))
+        if events:
+            with phase("prefill_commit", self._tracer):
+                self.pool.push_tables()
         yield from events
+
+    def _dispatch_chunk(self, req: Request, chunk: List[int]):
+        """Enqueue the next prefill chunk of ``req`` (right-padded to
+        ``page_size``) on its contiguous cache; returns the chunk's
+        last-position logits row (a device array, not read here)."""
+        import jax.numpy as jnp
+        eng = self.engine
+        tokens = np.zeros((1, self.page_size), np.int32)
+        tokens[0, :len(chunk)] = chunk
+        if self.obs is not None:
+            self.obs.tracer.begin(req.rid, "chunk", pos=req._cursor,
+                                  n=len(chunk))
+        row, req._contig = eng._prefill_chunk(
+            eng.params, jnp.asarray(tokens), req._contig,
+            jnp.asarray(req._cursor, jnp.int32),
+            jnp.asarray(len(chunk) - 1, jnp.int32))
+        req._cursor += len(chunk)
+        if self.obs is not None:
+            self.obs.tracer.end(req.rid, "chunk")
+        return row
+
+    def _sample_prefilled(self, req: Request, row) -> Optional[int]:
+        """The token a finished prefill samples from its last logits
+        ``row`` under the request's policy (the key advances when
+        sampled), or ``None`` when the logits hold NaN. Two host reads:
+        the NaN check, then the sampled token."""
+        import jax.numpy as jnp
+        if bool(np.isnan(np.asarray(row)).any()):
+            return None
+        if req.temperature > 0.0:
+            keys = self._request_key(req)[None]
+        else:
+            keys = jnp.zeros((1, 2), jnp.uint32)
+        toks, new_keys = self.engine._sample_rows(
+            row, keys, jnp.asarray([req.temperature], jnp.float32),
+            jnp.asarray([req.top_p], jnp.float32))
+        if req.temperature > 0.0:
+            req._key = new_keys[0]
+        return int(np.asarray(toks)[0])
+
+    def _activate(self, slot: int, req: Request, stream: List[int],
+                  tok0: int) -> StreamEvent:
+        """Move a finished prefill into the decode batch: scatter its
+        computed pages into the pool, donate its full prefill pages to
+        the radix tree, point ``slot`` at its pages (the caller pushes
+        the tables) and record its first token."""
+        ps = self.page_size
+        plen = len(stream)
+        self.pool.scatter_prefill(
+            req._contig, req.pages[req._first_page:pages_for(plen, ps)],
+            first_page=req._first_page)
+        req._contig = None
+        if self.prefix is not None:
+            self.prefix.insert(stream, req.pages[:plen // ps])
+        req.state = "active"
+        req.generated.append(tok0)
+        now = self._now()
+        req.t_first = now
+        req.tok_times.append(now)
+        self.pool.assign(slot, req.pages, pos=plen)
+        if self.obs is not None:
+            tr = self.obs.tracer
+            tr.end(req.rid, "prefill", t=now)
+            tr.instant(req.rid, "first_token", t=now, token=tok0)
+            tr.begin(req.rid, "decode", t=now)
+            self.obs.metrics.counter("sched.tokens").inc()
+        done = tok0 == req.eos_id or len(req.generated) >= req.max_new
+        tm = None
+        if done:
+            self._release(req)
+            tm = self._finish(req)
+        return StreamEvent(req.rid, tok0, done, t=now, timing=tm)
 
     # -- packed decode -----------------------------------------------------
 
@@ -897,14 +926,35 @@ class Scheduler:
         """One compiled step for every active slot — per-slot sampling
         state rides along; release finished sequences' pages the same
         step."""
-        import jax.numpy as jnp
         active = [i for i, s in enumerate(self._slots)
                   if s is not None and s.state == "active"]
         if not active:
             return
-        if self.obs is not None:
-            self.obs.tracer.begin(SCHED_TRACK, "decode_step",
-                                  batch=len(active))
+        with phase("decode_dispatch", self._tracer, rows=len(active)):
+            tok_next, bad = self._dispatch_decode(active)
+        with phase("decode_sync", self._tracer):
+            # this read blocks on the step just dispatched — the
+            # deliberate price of *same-step* page release and admission
+            # (the whole point of the paged pool); the lockstep loop,
+            # which never releases mid-batch, pipelines with a
+            # one-step-stale read instead (engine.generate_lockstep)
+            toks = np.asarray(tok_next)
+            # NaN-in-logits per batch row, read only for *active* rows
+            # (idle and prefilling slots ride the scratch page and may be
+            # NaN legitimately): a bad row means this request's
+            # block-table pages fed corruption into its logits — poison
+            # exactly it
+            bad_rows = np.asarray(bad)
+        with phase("decode_commit", self._tracer):
+            events = self._commit_decode(active, toks, bad_rows)
+        yield from events
+
+    def _dispatch_decode(self, active: List[int]):
+        """Enqueue one paged decode step over every slot (the ``active``
+        ones carry their last token and sampling state) and advance the
+        pool's host mirrors; returns the step's device ``(tokens, NaN
+        rows)``, not read here."""
+        import jax.numpy as jnp
         eng = self.engine
         w = self.decode_batch
         tok = np.zeros((w, 1), np.int32)
@@ -931,22 +981,16 @@ class Scheduler:
             req = self._slots[i]
             if req.temperature > 0.0:
                 req._key = new_keys[i]
-        # this read blocks on the step just dispatched — the deliberate
-        # price of *same-step* page release and admission (the whole
-        # point of the paged pool); the lockstep loop, which never
-        # releases mid-batch, pipelines with a one-step-stale read
-        # instead (engine.generate_lockstep)
-        toks = np.asarray(tok_next)
-        # NaN-in-logits per batch row, read only for *active* rows (idle
-        # and prefilling slots ride the scratch page and may be NaN
-        # legitimately): a bad row means this request's block-table
-        # pages fed corruption into its logits — poison exactly it
-        bad_rows = np.asarray(bad)
+        return tok_next, bad
+
+    def _commit_decode(self, active: List[int], toks,
+                       bad_rows) -> List[StreamEvent]:
+        """Record the step's tokens read on the host: poison rows whose
+        logits held NaN, append each token, release finished requests
+        and commit their cleared slots; returns the stream events."""
         # one clock read shared by every row: the step's tokens all
-        # became host-visible at the same blocking read above
+        # became host-visible at the same blocking read
         now = self._now()
-        if self.obs is not None:
-            self.obs.tracer.end(SCHED_TRACK, "decode_step", t=now)
         events = []
         released = False
         for i in active:
@@ -975,7 +1019,7 @@ class Scheduler:
             # stream must not resume with freed (and possibly
             # reallocated) pages still installed on the device
             self.pool.push_tables()
-        yield from events
+        return events
 
     def _release(self, req: Request) -> None:
         """Unreference the request's pages and free its slot the step

@@ -30,7 +30,7 @@ import pytest
 
 from repro.configs import get_arch
 from repro.models import model
-from repro.obs import (ServeObs, export, level, obs_from_env)
+from repro.obs import (ServeObs, export, level, obs_from_env, phase)
 from repro.obs.metrics import CompileWatcher, MetricsRegistry
 from repro.obs.trace import (SCHED_TRACK, RequestTiming, Tracer,
                              percentile)
@@ -107,6 +107,24 @@ def test_tracer_misnesting_raises():
         tr.end(0, "a")
     with pytest.raises(RuntimeError, match="mis-nesting"):
         tr.end(1, "a")                   # nothing open on that track
+
+
+def test_phase_helper_sinks():
+    """Both sinks off: the shared no-op, nothing built. With a tracer:
+    the span on SCHED_TRACK with its args, closed on exit — an
+    exception included."""
+    assert phase("decode_sync") is phase("admit", queued=3)
+    tr = Tracer(FakeClock())
+    with phase("decode_dispatch", tr, rows=4):
+        assert tr.open_depth(SCHED_TRACK) == 1
+    with pytest.raises(ValueError):
+        with phase("admit", tr, queued=2):
+            raise ValueError("boom")
+    spans = tr.track_spans(SCHED_TRACK)
+    assert [(s.name, s.args) for s in spans] == [
+        ("decode_dispatch", {"rows": 4}), ("admit", {"queued": 2})]
+    assert all(s.t1 > s.t0 for s in spans)
+    assert tr.open_depth(SCHED_TRACK) == 0
 
 
 def test_percentile_nearest_rank():
@@ -396,6 +414,31 @@ def test_fault_observer_does_not_change_schedule(base_cfg, params):
     ledger_obs, seen = run(True)
     assert ledger_obs == ledger_plain    # observation is not targeting
     assert seen == ledger_obs            # and the observer saw each one
+
+
+PHASES = ("housekeeping", "admit", "prefill_chunk", "prefill_sync",
+          "prefill_commit", "decode_dispatch", "decode_sync",
+          "decode_commit")
+
+
+def test_scheduler_phases_on_the_sched_track(base_cfg, params,
+                                             monkeypatch):
+    """With ``REPRO_OBS=1`` every phase lands on SCHED_TRACK inside its
+    tick, closed, and none spans a yield: the consumer's clock reading
+    between two stream events lies outside every phase."""
+    monkeypatch.setenv("REPRO_OBS", "1")
+    cfg = dataclasses.replace(base_cfg, kv_quant="takum8")
+    clock = FakeClock()
+    eng = _engine(params, cfg, now_fn=clock)
+    for p in _prompts(cfg, (11, 5, 19)):
+        eng.submit(p, 4)
+    seen = [clock() for _ in eng.run()]
+    spans = eng.obs.tracer.track_spans(SCHED_TRACK)
+    phases = [s for s in spans if s.name != "tick"]
+    assert {s.name for s in phases} == set(PHASES)
+    assert all(s.depth == 1 and s.t1 is not None for s in phases)
+    assert not any(s.t0 < t < s.t1 for s in phases for t in seen)
+    assert "decode_step" not in {s.name for s in spans}
 
 
 def test_trace_records_raises_when_off(base_cfg, params, monkeypatch):

@@ -17,7 +17,10 @@ schedule:
   * ``shared_pages`` counts pages with >1 owner, and ``hbm_bytes``
     counts every physical page once no matter how shared it is;
   * draining every request and clearing the tree returns the pool to
-    completely full.
+    completely full;
+  * the fill counters (``PageStats.fill_tokens``/``fill_capacity``)
+    equal the positions written and held by the rows of every decode
+    step, and never read above full.
 
 Across the module the randomized tests run >= 200 schedules (see
 ``max_examples`` totals) under the hypcompat shim.
@@ -63,6 +66,10 @@ class _Sim:
         self.live = {}
         self._rids = itertools.count()
         self.plans = 0                  # our side of the lookups ledger
+        # decode rows: rid -> [slot, decode steps left]; our side of the
+        # fill counters
+        self.rows = {}
+        self.fill_tokens = self.fill_capacity = 0
 
     def plan(self, prompt):
         self.plans += 1
@@ -96,11 +103,33 @@ class _Sim:
         self.prefix.insert(prompt, pages[:len(prompt) // ps])
         rid = next(self._rids)
         self.live[rid] = pages
+        taken = {slot for slot, _ in self.rows.values()}
+        free = [s for s in range(self.pool.batch) if s not in taken]
+        if free:
+            self.pool.assign(free[0], pages, pos=len(prompt))
+            self.rows[rid] = [free[0], max_new - 1]
         return rid
 
     def release(self, rid):
         for p in self.live.pop(rid):
             self.pool.unref(p)
+        if rid in self.rows:
+            self.pool.clear(self.rows.pop(rid)[0])
+
+    def decode_step(self):
+        """One decode step over the rows with steps left: each writes
+        one position, which must lie inside the pages it holds."""
+        ps = self.pool.page_size
+        active = sorted((slot, rid) for rid, (slot, left)
+                        in self.rows.items() if left > 0)
+        for slot, rid in active:
+            written = int(self.pool.pos[slot]) + 1
+            held = len(self.live[rid]) * ps
+            assert written <= held, "a decode write past the row's pages"
+            self.fill_tokens += written
+            self.fill_capacity += held
+            self.rows[rid][1] -= 1
+        self.pool.advance([slot for slot, _ in active])
 
 
 def _tree_pages(prefix):
@@ -158,6 +187,10 @@ def _check(sim):
     assert tree["lookups"] == sim.plans
     assert 0 <= tree["hits"] <= tree["lookups"]
     assert (tree["hit_tokens"] == 0) == (tree["hits"] == 0)
+    # the fill counters: exactly the rows' written and held positions
+    assert (stats.fill_tokens, stats.fill_capacity) == \
+        (sim.fill_tokens, sim.fill_capacity)
+    assert 0 <= stats.fill_tokens <= stats.fill_capacity
 
 
 def _prompt(a, b):
@@ -198,6 +231,7 @@ def test_refcount_invariants_under_random_schedule(num_pages, schedule):
             sim.prefix.clear()
         elif op == 9 and history:
             sim.admit(history[b % len(history)], max_new=1 + a % 6)
+        sim.decode_step()
         _check(sim)
     for rid in sorted(sim.live):
         sim.release(rid)

@@ -1,4 +1,4 @@
-"""BENCH_codec schema gate: schema 9 + `blocks` + prefix/fault/shard/obs rows.
+"""BENCH_codec schema gate: schema 9 + `blocks` + prefix/fault/shard rows.
 
     python tools/check_bench_schema.py BENCH_codec.smoke.json
 
@@ -19,14 +19,8 @@ rows at tp in {1, 2, 4, 8}, compressed collectives on and off. The
 gates: every compress-on row moves strictly fewer interconnect bytes
 than its f32 twin, tp=1 moves zero, and tp=8 device-normalized
 throughput is >= tp=1 under both compress settings (the scaling claim
-the PR makes). Schema 9 adds the ``serving_obs`` section: the same
-continuous-batching workload with ``REPRO_OBS`` unset and at level 1.
-The gates: level-1 overhead <= 5% (``overhead_pct``, best round vs
-best round — observability must be cheap enough to leave on),
-``recompiles_steady_state == 0`` (the armed compile watcher saw no
-retrace after warmup) and ``token_parity`` true (the traced run
-generated bit-identical tokens). TTFT and goodput *magnitudes* are not
-gated — wall-clock comparisons belong in the artifact, not a CI assert.
+the PR makes). TTFT and goodput *magnitudes* are not gated —
+wall-clock comparisons belong in the artifact, not a CI assert.
 """
 
 import json
@@ -47,12 +41,6 @@ SHARDED_FIELDS = ("tp", "compress", "steps", "decode_batch", "us",
                   "tokens_per_s_wall", "tokens_per_s", "normalization",
                   "interconnect_bytes_per_step", "pool_shard_bytes",
                   "path")
-OBS_FIELDS = ("repro_obs", "n_requests", "max_new", "timed_rounds",
-              "us", "us_best", "tokens_per_s", "path")
-OBS_ON_FIELDS = OBS_FIELDS + ("overhead_pct", "token_parity",
-                              "recompiles_steady_state",
-                              "compiles_total", "trace_spans")
-OBS_OVERHEAD_PCT_MAX = 5.0
 
 
 def check(path: str) -> None:
@@ -145,25 +133,6 @@ def check(path: str) -> None:
         assert t8 >= t1, \
             (f"{path}: tp=8 normalized throughput {t8} < tp=1 {t1} "
              f"(compress={side}) — sharding does not scale")
-    obs = doc.get("serving_obs") or {}
-    for key, fields in (("obs/takum8/off", OBS_FIELDS),
-                        ("obs/takum8/on", OBS_ON_FIELDS)):
-        assert key in obs, f"{path}: serving_obs missing {key!r} row"
-        for field in fields:
-            assert obs[key].get(field) is not None, \
-                f"{path}: serving_obs/{key} missing {field}"
-    obs_on = obs["obs/takum8/on"]
-    assert obs_on["overhead_pct"] <= OBS_OVERHEAD_PCT_MAX, \
-        (f"{path}: REPRO_OBS=1 costs {obs_on['overhead_pct']}% > "
-         f"{OBS_OVERHEAD_PCT_MAX}% — observability is not cheap enough "
-         "to leave on")
-    assert obs_on["recompiles_steady_state"] == 0, \
-        (f"{path}: {obs_on['recompiles_steady_state']} steady-state "
-         "recompile(s) with obs on — tracing perturbed the compiled path")
-    assert obs_on["token_parity"] is True, \
-        f"{path}: traced run generated different tokens — obs is not neutral"
-    assert obs_on["trace_spans"] > 0, \
-        f"{path}: obs-on run recorded no spans — tracing is dead"
     print(f"# {path}: schema 9 ok — {n_rows} kernel rows with blocks, "
           f"{len(roof)} roofline points, {len(on_rows)} prefix serving "
           f"pair(s), hit_rate="
@@ -173,9 +142,7 @@ def check(path: str) -> None:
           f"normalized={sharded['tp8/off']['tokens_per_s']}/"
           f"{sharded['tp1/off']['tokens_per_s']} tok/s, compressed "
           f"bytes/step={sharded['tp8/on']['interconnect_bytes_per_step']}"
-          f" vs f32 {sharded['tp8/off']['interconnect_bytes_per_step']}, "
-          f"obs overhead={obs_on['overhead_pct']}% "
-          f"(recompiles={obs_on['recompiles_steady_state']})")
+          f" vs f32 {sharded['tp8/off']['interconnect_bytes_per_step']}")
 
 
 if __name__ == "__main__":
