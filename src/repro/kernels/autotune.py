@@ -35,8 +35,9 @@ Two JSON tables, local overriding checked-in:
 
 ``REPRO_AUTOTUNE`` picks the mode:
 
-* ``0`` — off: lookups return nothing, callers use their hand-picked
-  fallbacks (the pre-autotuner behaviour, bit for bit);
+* ``0`` — off: lookups return nothing, callers use their blockless
+  fallbacks (``ops.qmm_blocks``' shape-derived tile for ``qmatmul``,
+  the fixed tiles elsewhere);
 * ``1`` (default) — lookup only: consult the tables, never sweep.
   This is the CI mode — ``make bench-smoke`` runs with it so CI
   validates the table without paying for a sweep;
@@ -44,7 +45,7 @@ Two JSON tables, local overriding checked-in:
   (what ``make autotune`` / ``python -m repro.kernels.autotune`` use).
 
 Sweeps are honest by construction: every sweep space starts with the
-hand-picked fallback and selection is strict-improvement, so the tuned
+blockless fallback and selection is strict-improvement, so the tuned
 result beats or matches the old default on every row — on backends where
 the blocks cannot matter (the XLA fallback path ignores them) the
 fallback simply wins its ties.
@@ -160,7 +161,7 @@ def lookup(op: str, fmt: str, bucket: str,
     """The tuned blocks for a key, or None (miss, or REPRO_AUTOTUNE=0).
 
     This is what the ``ops`` wrappers consult whenever the caller passes
-    no explicit ``block=``; a miss falls back to the hand-picked default
+    no explicit ``block=``; a miss falls back to the blockless default
     at the call site.
     """
     if mode() == "0":
@@ -309,7 +310,9 @@ def _sweep_all(log=print, write_defaults: bool = False) -> dict:
             spec = formats.get(name)
             ww = spec.encode_tile(w)
             bucket = matmul_bucket(m, k, nn)
-            fb = ops.default_qmm_blocks(m)
+            # the first candidate is what a blockless call takes on a miss
+            fb = (ops.qmm_blocks(m, k, nn, spec.bytes_per_elem())
+                  if op == "qmatmul" else ops.default_qmm_blocks(m))
             log(f"# sweep {op}/{name} {bucket} [{backend}]")
             blocks, us, _ = cached_or_sweep(
                 op, name, bucket, qmm_space(fb),

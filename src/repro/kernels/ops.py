@@ -30,11 +30,13 @@ from repro.kernels import ref as kref
 from repro.kernels import lns_matmul as klns
 from repro.kernels import takum_attention as kattn
 from repro.kernels import takum_codec, takum_matmul, quantize as kquant
+from repro.obs.metrics import GLOBAL
 
 __all__ = ["takum_decode", "takum_encode", "fake_quant_fused", "quant_matmul",
            "lns_matmul", "takum_attention", "paged_attention",
            "interpret_default", "WireMatrix", "default_qmm_blocks",
-           "default_attention_bk", "resolved_blocks"]
+           "qmm_blocks", "qmm_vmem_bytes", "default_attention_bk",
+           "resolved_blocks"]
 
 
 def interpret_default() -> bool:
@@ -168,21 +170,80 @@ def quant_matmul(x, w_words, fmt, use_kernel: bool = True,
     ``block = (bm, bn, bk)`` overrides the weight-stationary kernel's
     tile sizes; ``None`` consults the autotune table
     (``kernels/autotune.py`` — per format, shape bucket and backend,
-    ``REPRO_AUTOTUNE`` gates it) and falls back to the MXU-shaped
-    defaults on a miss, with ``bm`` clamped to the padded M so small
-    serving batches don't round up to a full 128-row tile.
+    ``REPRO_AUTOTUNE`` gates it) and on a miss derives the tile from the
+    shape (:func:`qmm_blocks`: one M-step up to 256 rows, the largest
+    VMEM-fitting tile of 128-multiples dividing K and N). Each kernel
+    call adds its grid-step count to the ``qmatmul.grid_steps`` counter
+    of ``repro.obs.metrics.GLOBAL`` at trace time.
     """
     return _quant_matmul_fwd_impl(x, w_words, fmt, use_kernel, interpret,
                                   block)
 
 
 def default_qmm_blocks(m0: int) -> tuple:
-    """The hand-picked matmul tile default: MXU-shaped, with ``bm``
-    clamped to the padded M so small serving batches don't round up to a
-    full 128-row tile. This is both the pre-autotuner behaviour and the
-    first candidate of every autotune sweep."""
+    """The fixed MXU-shaped tile ``(bm, 128, 128)``, with ``bm`` clamped
+    to the padded M so small serving batches don't round up to a full
+    128-row tile. The blockless ``lns_matmul`` fallback, and the first
+    candidate of every autotune sweep; a blockless ``quant_matmul``
+    derives its tile from the shape instead (:func:`qmm_blocks`)."""
     bm = min(takum_matmul.DEFAULT_BM, max(8, -(-m0 // 8) * 8))
     return (bm, takum_matmul.DEFAULT_BN, takum_matmul.DEFAULT_BK)
+
+
+QMM_ONE_STEP_M = 256  # padded rows up to this run as one M-step
+# under the v5e's 16 MiB scoped VMEM default: at M = 256 Mosaic refused
+# tiles whose qmm_vmem_bytes estimate reached ~14.9 MB
+QMM_VMEM_BUDGET = 12 * 1024 * 1024
+# weight words per grid step: twice as many gained under 1% on a v5e
+QMM_MAX_TILE_WORDS = 1280 * 896
+
+
+def qmm_vmem_bytes(m: int, bm: int, bk: int, bn: int,
+                   word_bytes: int) -> int:
+    """VMEM the weight-stationary kernel holds at blocks ``(bm, bn, bk)``
+    for ``m`` padded rows: the double-buffered word blocks, the f32
+    decoded tile, the double-buffered x blocks and their f32 cast, the
+    double-buffered (M, bn) f32 output stripe and one step's product."""
+    return (2 * word_bytes * bk * bn + 4 * bk * bn + 3 * 4 * bm * bk
+            + 2 * 4 * m * bn + 4 * bm * bn)
+
+
+def _tiles_of(dim: int) -> list:
+    """Multiples of 128 that divide ``dim`` rounded up to 128."""
+    units = -(-dim // 128)
+    return [128 * d for d in range(1, units + 1) if units % d == 0]
+
+
+def qmm_blocks(m0: int, k0: int, n0: int, word_bytes: int = 1) -> tuple:
+    """The tile a blockless ``quant_matmul`` call derives from its shape.
+
+    ``bm`` covers the padded M in one M-step up to ``QMM_ONE_STEP_M``
+    rows (the decode step and the prefill chunk), else 128. ``bk`` and
+    ``bn`` are the multiples of 128 that divide K and N rounded up to
+    128 — so no call pads more than the 128 tile did — with the most
+    weight words per grid step (at most ``QMM_MAX_TILE_WORDS``, ties to
+    the wider ``bn``: fewer re-reads of x) whose :func:`qmm_vmem_bytes`
+    stays within ``QMM_VMEM_BUDGET``. A grid step has a fixed cost;
+    a 5120x17920 projection at M = 32 takes 80 steps instead of 5600.
+    Calls whose
+    (M, 128) stripe already exceeds the kernel's accumulator budget run
+    its M-outer schedule at the 128 tile, as before."""
+    mp = max(8, -(-m0 // 8) * 8)
+    bm = mp if mp <= QMM_ONE_STEP_M else takum_matmul.DEFAULT_BM
+    mp = -(-mp // bm) * bm
+    best = (bm, takum_matmul.DEFAULT_BN, takum_matmul.DEFAULT_BK)
+    if mp * best[1] * 4 > takum_matmul.DEFAULT_ACC_BUDGET:
+        return best
+    for bk in _tiles_of(k0):
+        for bn in _tiles_of(n0):
+            if (bk * bn > QMM_MAX_TILE_WORDS
+                    or mp * bn * 4 > takum_matmul.DEFAULT_ACC_BUDGET
+                    or qmm_vmem_bytes(mp, bm, bk, bn, word_bytes)
+                    > QMM_VMEM_BUDGET):
+                continue
+            if (bk * bn, bn) > (best[2] * best[1], best[1]):
+                best = (bm, bn, bk)
+    return best
 
 
 def default_attention_bk() -> int:
@@ -200,7 +261,11 @@ def _qmm_blocks(spec, m0: int, k0: int, n0: int, block: tuple | None,
         return block
     tuned = autotune.lookup(op, spec.name,
                             autotune.matmul_bucket(m0, k0, n0))
-    return tuned if tuned is not None else default_qmm_blocks(m0)
+    if tuned is not None:
+        return tuned
+    if op == "qmatmul":
+        return qmm_blocks(m0, k0, n0, spec.bytes_per_elem())
+    return default_qmm_blocks(m0)
 
 
 def resolved_blocks(op: str, spec_name, shape) -> tuple:
@@ -234,6 +299,10 @@ def _matmul_fwd_common(x, w_words, spec, use_kernel, interpret, block, *,
     bm, bn, bk = _qmm_blocks(spec, m0, x2.shape[1], n0, block, op)
     xp = _pad_to(prep_fn(x2), bm, bk)
     wp = _pad_to(w_words, bk, bn)
+    # grid steps this call compiles to, counted at trace time (a served
+    # executable adds its steps once, at its compile)
+    GLOBAL.counter(f"{op}.grid_steps").inc(
+        (xp.shape[0] // bm) * (wp.shape[0] // bk) * (wp.shape[1] // bn))
     out = kernel_fn(xp, wp, bm, bn, bk, interpret_)
     return out[:m0, :n0].reshape(*lead, n0)
 

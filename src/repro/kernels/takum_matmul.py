@@ -35,8 +35,20 @@ fall back to the classic M-outer/K-innermost schedule, where consecutive
 K steps accumulate directly in a ``(bm, bn)`` output block (one decode
 per ``(i, j, kk)`` — correct, just not decode-once).
 
-Block sizes ``(bm, bn, bk)`` are caller-tunable through
-``ops.quant_matmul`` for autotuning; defaults match the MXU tile.
+Tile size and strip-wise decode
+-------------------------------
+A grid step has a fixed cost (DMA issue and wait, semaphores, the scalar
+loop): ~0.08 us on a v5e, against ~0.53 us for the decode and dot of a
+128x128 takum8 tile at M = 32. So a blockless ``ops.quant_matmul`` call
+derives ``(bm, bn, bk)`` from its shape (``ops.qmm_blocks``): one M-step
+for up to 256 rows, and the largest tile of 128-multiples dividing K and
+N that fits the VMEM budget — a few dozen grid steps per projection
+instead of thousands (~12% less time for a 5120x17920 projection). A
+large tile is decoded into the scratch strip by strip (``DEFAULT_STRIP``
+pieces, a ``fori_loop``), so each trip keeps a few vregs live; Mosaic
+unrolls a whole-tile decode, which spills and takes minutes to compile
+at 1280x896. Block sizes stay caller-tunable through
+``ops.quant_matmul`` (``block=``) and the autotune table.
 """
 
 from __future__ import annotations
@@ -54,9 +66,44 @@ __all__ = ["qmatmul_kernel_call", "DEFAULT_ACC_BUDGET"]
 
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 128, 128
 DEFAULT_ACC_BUDGET = 4 * 1024 * 1024  # VMEM bytes for the (M, bn) stripe
+# (rows, cols) of the weight tile decoded per loop trip, and the largest
+# tile decoded whole: on a v5e a (64, 256) strip beat (8|32, 128|256|512)
+# by 0-4% at 1024x1024 and 1280x896 tiles, and the loop cost a 128x128
+# tile 6% against decoding it whole
+DEFAULT_STRIP = (64, 256)
+WHOLE_TILE_WORDS = 128 * 128
 # decoded takum16/posit16 weights are not bf16-exact: keep the MXU at f32
 # (multi-pass), matching the f32 oracle ``kernels/ref.qmatmul_ref``
 F32_DOT = jax.lax.Precision.HIGHEST
+
+
+def _decode_into(w_ref, dst_ref, spec: formats.FormatSpec):
+    """Decode the (bk, bn) word block into the f32 ``dst_ref`` one
+    ``DEFAULT_STRIP`` piece per loop trip, so a large tile keeps a few
+    vregs live per intermediate instead of spilling the whole tile's;
+    every piece goes through the same ``spec.decode_tile``, so the
+    result is bit-identical to a whole-tile decode. A strip width that
+    does not divide the tile is halved down to 128 columns (896 and 640
+    wide tiles); a tile of at most ``WHOLE_TILE_WORDS``, or that no
+    strip divides, decodes whole (Mosaic unrolls a whole-tile decode:
+    minutes to compile at 1280x896)."""
+    bk, bn = w_ref.shape
+    sr, sc = min(DEFAULT_STRIP[0], bk), min(DEFAULT_STRIP[1], bn)
+    while bn % sc and sc > 128:
+        sc //= 2
+    if bk * bn <= WHOLE_TILE_WORDS or bk % sr or bn % sc:
+        dst_ref[...] = spec.decode_tile(w_ref[...], dtype=jnp.float32)
+        return
+    ncol = bn // sc
+
+    def body(s, carry):
+        rows = pl.ds(pl.multiple_of((s // ncol) * sr, sr), sr)
+        cols = pl.ds(pl.multiple_of((s % ncol) * sc, sc), sc)
+        dst_ref[rows, cols] = spec.decode_tile(w_ref[rows, cols],
+                                               dtype=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, (bk // sr) * ncol, body, 0)
 
 
 def _qmm_ws_tile(x_ref, w_ref, o_ref, wdec_ref, *,
@@ -67,7 +114,7 @@ def _qmm_ws_tile(x_ref, w_ref, o_ref, wdec_ref, *,
 
     @pl.when(i == 0)
     def _decode():  # once per (j, kk): all M steps reuse wdec_ref
-        wdec_ref[...] = spec.decode_tile(w_ref[...], dtype=jnp.float32)
+        _decode_into(w_ref, wdec_ref, spec)
 
     part = jnp.dot(
         x_ref[...].astype(jnp.float32), wdec_ref[...], precision=F32_DOT,

@@ -374,10 +374,10 @@ def test_blockless_ops_consult_cache(tmp_path, monkeypatch):
     want = kref.qmatmul_ref(x, w_words, 16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
-    # off: the same call must fall back to the hand-picked default
+    # off: the same call must fall back to the shape-derived default
     monkeypatch.setenv("REPRO_AUTOTUNE", "0")
     assert ops.resolved_blocks("qmatmul", "takum16", (40, 96, 128)) == \
-        ops.default_qmm_blocks(40)
+        ops.qmm_blocks(40, 96, 128, 2)
 
 
 def test_autotune_defaults_table_is_valid():

@@ -3,11 +3,12 @@
 Each test lowers a kernel through Mosaic for one chip of a *described*
 ``v5e:2x2`` topology (no chip attached) at the widths the chip smoke
 serves — phi3-medium-14b: d_model 5120, d_ff 17920, 40 heads over 10 KV
-heads of 128, 64-position pages — and asserts the compiled program holds
-a ``tpu_custom_call``. Blocks are the ones the TPU would use: the
-``|tpu`` entries of ``kernels/autotune_defaults.json``, else the
-hand-picked defaults. Interpret-mode tests cannot see what this file
-guards: tiling rules, unsupported primitives and VMEM limits.
+heads of 128, 256-position pages, 32 decode rows — and asserts the
+compiled program holds a ``tpu_custom_call``. Blocks are the ones the
+TPU would use: the ``|tpu`` entries of ``kernels/autotune_defaults.json``,
+else the blockless defaults (``quant_matmul``'s shape-derived tile).
+Interpret-mode tests cannot see what this file guards: tiling rules,
+unsupported primitives and VMEM limits.
 
 The topology is described inside a module fixture, never at import: the
 TPU library admits one process at a time, and every test worker imports
@@ -25,7 +26,7 @@ from repro import formats
 from repro.kernels import autotune, ops
 
 D, FF, H, HKV, HD = 5120, 17920, 40, 10, 128
-PAGE, DECODE_BATCH, CONTEXT = 64, 8, 1088
+PAGE, DECODE_BATCH, CONTEXT = 256, 32, 1088
 
 
 @pytest.fixture(scope="module")
@@ -76,15 +77,18 @@ def test_posit8_table_decode_lowers(one_chip, monkeypatch):
              ((D, FF), jnp.uint8))
 
 
-@pytest.mark.parametrize("m", [DECODE_BATCH, PAGE])
-def test_quant_matmul_lowers(one_chip, m):
+@pytest.mark.parametrize("k,n", [(D, D), (D, HKV * HD), (D, FF), (FF, D)],
+                         ids=["wq_wo", "wk_wv", "wg_w1", "w2"])
+@pytest.mark.parametrize("m", [DECODE_BATCH, PAGE],
+                         ids=["decode", "prefill_chunk"])
+def test_quant_matmul_lowers(one_chip, m, k, n):
     blocks = _tpu_blocks("qmatmul", "takum8",
-                         autotune.matmul_bucket(m, D, FF),
-                         ops.default_qmm_blocks(m))
+                         autotune.matmul_bucket(m, k, n),
+                         ops.qmm_blocks(m, k, n))
     _compile(one_chip,
              lambda x, w: ops.quant_matmul(x, w, "takum8", True, False,
                                            blocks),
-             ((m, D), jnp.bfloat16), ((D, FF), jnp.uint8))
+             ((m, k), jnp.bfloat16), ((k, n), jnp.uint8))
 
 
 def test_lns_matmul_lowers(one_chip):
