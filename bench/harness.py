@@ -12,15 +12,18 @@
    that keeps the load on until every request due in the window has its
    first token (at most ``postroll_s``);
 3. the check: a seeded sample of the requests the window finished, the
-   longest among them, against the float32 reference (``reference.py``),
-   after the engine is freed;
+   longest among them, against the float32 reference of the
+   configuration's architecture module, after the engine is freed;
 4. the result: one JSON line with the cell's end-to-end metrics
    (``--trace 0``) or its per-layer metrics read from a profiler trace of
    the window (``--trace 1``).
 
 Everything that belongs to one configuration, traffic mix or metric is a
 file found by its name in ``BENCHMARK.json``: ``configs/<file>``,
-``traffic/<traffic>.json``, ``metrics/<metric>.py``.
+``traffic/<traffic>.json``, ``metrics/<metric>.py``; and a configuration
+names its architecture, ``arch/<arch>.py``, which brings the weights,
+the reference and the counts that depend on the model's shape
+(``arch/__init__.py``).
 """
 
 from __future__ import annotations
@@ -33,6 +36,7 @@ import math
 import os
 import shutil
 import sys
+import tempfile
 import time
 from typing import Dict, List, Optional
 
@@ -69,21 +73,42 @@ def find(entries: List[dict], name: str, what: str) -> dict:
 
 
 def load_cell(spec: dict, workload: str, root: str = ROOT):
-    """(cell, configuration file, traffic parameters)."""
+    """(cell, configuration file, architecture module, traffic
+    parameters)."""
     import traffic
     cell = find(spec["workloads"], workload, "workload")
     centry = find(spec["configs"], cell["config"], "config")
     with open(os.path.join(root, centry["file"])) as f:
         cfg = json.load(f)
-    return cell, cfg, traffic.load(cell["traffic"], os.path.join(root, "bench"))
+    return cell, cfg, load_arch(cfg.get("arch"), root), \
+        traffic.load(cell["traffic"], os.path.join(root, "bench"))
 
 
-def model_dict(cfg: dict) -> dict:
-    """The sizes the weights, the reference and the cost functions use."""
-    m = cfg["model"]
-    return {k: m[k] for k in ("n_layers", "d_model", "n_heads", "n_kv_heads",
-                              "head_dim", "d_ff", "vocab", "activation",
-                              "rope_base")}
+def _exec_file(mod_name: str, path: str):
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_arch(name: Optional[str], root: str = ROOT):
+    """The architecture module ``bench/arch/<name>.py`` that a
+    configuration names under ``"arch"``, loaded once per process (its
+    jitted reference layers keep their compiled programs from run to
+    run)."""
+    folder = os.path.join(root, "bench", "arch")
+    known = sorted(f[:-3] for f in os.listdir(folder)
+                   if f.endswith(".py") and not f.startswith("_"))
+    if name not in known:
+        raise KeyError(f"no architecture module {name!r} (a configuration "
+                       f"names one under \"arch\"); known under {folder}: "
+                       f"{known}")
+    path = os.path.join(folder, f"{name}.py")
+    mod_name = "bench_arch_" + name.replace(".", "_").replace("-", "_")
+    mod = sys.modules.get(mod_name)
+    if mod is None or mod.__file__ != path:
+        mod = sys.modules[mod_name] = _exec_file(mod_name, path)
+    return mod
 
 
 def metrics_for(spec: dict, cell: str, trace: bool) -> List[dict]:
@@ -99,10 +124,7 @@ def reader(name: str, root: str = BENCH):
         path = os.path.join(root, "metrics", f"{stem}.py")
         if os.path.exists(path):
             mod_name = "bench_metric_" + stem.replace(".", "_").replace("-", "_")
-            spec = importlib.util.spec_from_file_location(mod_name, path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return _exec_file(mod_name, path).read
     raise FileNotFoundError(f"no reader for metric {name!r} under "
                             f"{os.path.join(root, 'metrics')}")
 
@@ -259,14 +281,15 @@ class LoadGen:
 
 
 class Run:
-    """What the metric readers see: the cell, its sizes and every
-    request's stamps, the window, the pool counters and the reduced
-    trace (``None`` without ``--trace 1``)."""
+    """What the metric readers see: the cell, its architecture module and
+    sizes, every request's stamps, the window, the pool counters and the
+    reduced trace (``None`` without ``--trace 1``)."""
 
-    def __init__(self, cell, cfg, seconds):
+    def __init__(self, cell, cfg, arch, seconds):
         self.cell = cell
         self.cfg = cfg
-        self.model = model_dict(cfg)
+        self.arch = arch
+        self.model = arch.model(cfg)
         self.engine = cfg["engine"]
         self.seconds = seconds
         self.t0 = self.t1 = 0.0
@@ -320,16 +343,16 @@ def percentile(vals: List[float], q: float) -> float:
     return s[max(0, min(len(s) - 1, math.ceil(q / 100.0 * len(s)) - 1))]
 
 
-def build(cfg: dict, seed: int):
+def build(run: Run, seed: int):
     """Weights and engine."""
     import jax
-    import weights
     from repro.configs.base import ModelConfig
     from repro.serve.engine import ServeEngine
     t = clock()
-    params = weights.build_params(seed, model_dict(cfg))
+    params = run.arch.build_params(seed, run.model)
     jax.block_until_ready(params)
     t_w = clock() - t
+    cfg = run.cfg
     e = cfg["engine"]
     eng = ServeEngine(params, ModelConfig(**cfg["model"]),
                       max_len=e["max_len"], page_size=e["page_size"],
@@ -423,19 +446,19 @@ def trace_mod():
 def check(run: Run, cfg: dict, seed: int, sample: List[tuple],
           control: bool = False):
     """``(checks, control_checks)``: the compared numbers, each with its
-    limit, for the tokens the program served; with ``control=True`` the
-    same numbers for the control's picks put in the program's place (the
-    reference with int4 weights choosing its own first token at each
-    served position), else ``None``. :func:`correct_from` judges both."""
+    limit, for the tokens the program served, against the reference of
+    ``run.arch``; with ``control=True`` the same numbers for the control's
+    picks put in the program's place (the reference with int4 weights
+    choosing its own first token at each served position), else
+    ``None``. :func:`correct_from` judges both."""
     import numpy as np
-    import reference
     lim = cfg["check"]
     vocab = run.model["vocab"]
     if not sample:
         empty = {"sampled_tokens": {"value": 0, "limit": lim["sample_tokens"]}}
         return empty, (dict(empty) if control else None)
     served = sum(len(g) for _, g in sample)
-    gaps, ctl_gaps = reference.served_gaps(seed, run.model, sample, control)
+    gaps, ctl_gaps = run.arch.served_gaps(seed, run.model, sample, control)
 
     def numbers(picks_in_vocab: bool, gap_list) -> dict:
         return {"max_logit_gap": {"value": float(max(float(np.max(g))
@@ -471,8 +494,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     import jax
     import traffic as traffic_lib
     spec = spec or load_spec(root)
-    cell, cfg, tparams = load_cell(spec, workload, root)
-    cfg = cfg_override or cfg
+    cell, cfg, arch, tparams = load_cell(spec, workload, root)
+    if cfg_override:
+        cfg, arch = cfg_override, load_arch(cfg_override.get("arch"), root)
     tparams = traffic_override or tparams
     dev = require_chips(cell["chips"]) if chips_check else device_info()
     cache_dir = enable_compile_cache(root) if compile_cache else "off"
@@ -483,16 +507,18 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         os.environ["REPRO_AUTOTUNE_CACHE"] = table
     complog = CompileLog().start()
     lines = [f"device {dev}; compile cache {cache_dir}"]
-    run = Run(cell, cfg, seconds)
+    run = Run(cell, cfg, arch, seconds)
     run.device_kind = dev["kind"]
-    eng, run.setup_split["weights_s"] = build(cfg, seed)
+    eng, run.setup_split["weights_s"] = build(run, seed)
     tobj = traffic_lib.generate(tparams, seed, run.model["vocab"], seconds,
                                 cfg["engine"]["page_size"])
     trace_dir = None
     if trace:
-        trace_dir = os.path.join(root, "bench_out", "trace", workload)
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        os.makedirs(trace_dir, exist_ok=True)
+        # a directory of this run's own: two runs from one checkout (the
+        # tests' workers) must not read or delete each other's trace
+        parent = os.path.join(root, "bench_out", "trace")
+        os.makedirs(parent, exist_ok=True)
+        trace_dir = tempfile.mkdtemp(prefix=f"{workload}.", dir=parent)
     serve_window(eng, run, tobj, jax.profiler.TraceAnnotation, trace_dir,
                  setup_t0)
     run.setup_split["compile_s"] = complog.seconds("backend_compile", 0, run.t0)

@@ -1,6 +1,7 @@
 """step_mfu (step programs): model FLOPs of the tokens the prefill and
-decode executables processed in the window, over their device time
-times the chip's bf16 peak (``peaks.json``), in percent.
+decode executables processed in the window (the architecture module's
+``model_flops``), over their device time times the chip's bf16 peak
+(``peaks.json``), in percent.
 
 Decode tokens are the window's output tokens after each request's first
 (each one row of a decode step, attending over its position's keys).
@@ -30,6 +31,6 @@ def read(run):
     for r in admitted:
         first = r["plen"] - int(round(keep * r["plen"]))
         ctx += list(range(first + 1, r["plen"] + 1))
-    flops = costs.model_flops(run.model, ctx)
+    flops = run.arch.model_flops(run.model, ctx)
     peak = costs.peaks(run.device_kind)["bf16_flops_per_s"]
     return 100.0 * flops / (sum(times) * peak)

@@ -1,22 +1,20 @@
-"""The plain reference: a dense GQA decoder in float32 ``jax.numpy``.
+"""The plain reference's shared pieces: float32 ``jax.numpy`` at
+``Precision.HIGHEST``, with no cache, batching, kernel or wire format.
 
-It follows the published layer equations of the configurations under
-``configs/`` (pre-norm RMSNorm blocks, rotary position embedding on the
-first and second halves of each head, grouped-query causal softmax
-attention, a SwiGLU or tanh-GELU MLP, a final RMSNorm and an untied
-unembedding), with every matmul at ``Precision.HIGHEST`` and no cache,
-batching, kernel or wire format. It imports nothing of the program: the
-weights are regenerated from the seed by ``weights.py`` and the takum8
-words decoded through :func:`takum8_table`, built here from the format's
-definition.
-
-It runs layer by layer over a few whole sequences (prompt plus served
-tokens), so one layer's float32 weights are all that it holds beside the
-activations, and returns the logits at the served positions only.
+It imports nothing of the program. An architecture module
+(``arch/<arch>.py``) regenerates its weights from the seed through
+``weights.py``, decodes the takum8 words with :func:`decode`, built here
+from the format's definition, and writes its own layer from the pieces
+below: :func:`rmsnorm`, :func:`rope`, :func:`causal_attention`.
+:func:`forward_logits` runs such a layer layer by layer over a few whole
+sequences (prompt plus served tokens), so one layer's float32 weights
+are all that it holds beside the activations, and returns the logits at
+the served positions only; :func:`served_gaps` turns them into the
+check's gaps.
 
 The control computes the same forward with each decoded weight matrix
-rounded to int4 (symmetric, one scale per output column): the next
-precision below the configuration's 8-bit weights.
+rounded to int4 (:func:`int4_columns`: symmetric, one scale per output
+column), the next precision below the configurations' 8-bit weights.
 """
 
 from __future__ import annotations
@@ -88,20 +86,15 @@ def mm(a, b):
     return jnp.matmul(a, b, precision=HI)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items",))
-def block(x, n_valid, w, cfg_items):
-    """One decoder layer over one sequence ``x [T, d]`` whose first
-    ``n_valid`` rows are real (the rest are padding, causally after)."""
-    cfg = dict(cfg_items)
-    t = x.shape[0]
-    h, hkv, hd = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-    g = h // hkv
+def causal_attention(q, k, v):
+    """Grouped-query causal softmax attention of one sequence: ``q [T, H,
+    hd]``, ``k`` and ``v [T, Hkv, hd]`` (query head i reads KV head
+    ``i // (H // Hkv)``), in query blocks of ``BUCKET`` rows; ``[T, H *
+    hd]``."""
+    t, h, hd = q.shape
+    g = h // k.shape[1]
     pos = jnp.arange(t)
-    a = rmsnorm(x)
-    q = rope(mm(a, w["wq"]).reshape(t, h, hd), pos, cfg["rope_base"])
-    k = rope(mm(a, w["wk"]).reshape(t, hkv, hd), pos, cfg["rope_base"])
-    v = mm(a, w["wv"]).reshape(t, hkv, hd)
-    k = jnp.repeat(k, g, axis=1)          # query head i reads kv head i // g
+    k = jnp.repeat(k, g, axis=1)
     v = jnp.repeat(v, g, axis=1)
 
     def attend(qb, q0):
@@ -114,16 +107,8 @@ def block(x, n_valid, w, cfg_items):
 
     nb = t // BUCKET
     qs = q.reshape(nb, BUCKET, h, hd)
-    o = jax.lax.map(lambda args: attend(*args),
-                    (qs, jnp.arange(nb) * BUCKET)).reshape(t, h * hd)
-    x = x + mm(o, w["wo"])
-    a = rmsnorm(x)
-    if cfg["activation"] == "swiglu":
-        hid = jax.nn.silu(mm(a, w["wg"])) * mm(a, w["w1"])
-    else:
-        hid = jax.nn.gelu(mm(a, w["w1"]), approximate=True)
-    x = x + mm(hid, w["w2"])
-    return jnp.where((pos < n_valid)[:, None], x, 0.0)
+    return jax.lax.map(lambda args: attend(*args),
+                       (qs, jnp.arange(nb) * BUCKET)).reshape(t, h * hd)
 
 
 @functools.partial(jax.jit, static_argnames=("vocab",))
@@ -132,21 +117,14 @@ def head(x, rows, unembed, vocab):
     return mm(rmsnorm(x[rows]), unembed[:, :vocab])
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "control"))
-def _layer_weights(layer, key, cfg_items, control):
-    words = W.layer_words(key, dict(cfg_items), layer)
-    out = {k: decode(v) for k, v in words.items()}
-    if control:
-        out = {k: int4_columns(v) for k, v in out.items()}
-    return out
-
-
 def forward_logits(seed: int, cfg: dict, seqs: Sequence[List[int]],
-                   rows: Sequence[np.ndarray], control: bool = False):
+                   rows: Sequence[np.ndarray], layer, control: bool = False):
     """Reference logits of each sequence at its ``rows`` (float32 numpy
-    arrays ``[len(rows_i), vocab]``). ``control=True``: int4 weights."""
-    items = tuple(sorted((k, v) for k, v in cfg.items()
-                         if isinstance(v, (int, float, str))))
+    arrays ``[len(rows_i), vocab]``). ``layer(i, xs, lens)`` runs layer
+    ``i`` over every sequence ``xs`` (``[T, d]``, padded to a multiple of
+    ``BUCKET``; the first ``lens`` rows real) and returns them; the
+    embeddings are ``weights.embeddings``'. ``control=True``: int4
+    unembedding (the layer rounds its own weights)."""
     emb, unemb = W.embeddings(seed, cfg)
     if control:
         unemb = int4_columns(unemb)
@@ -157,11 +135,8 @@ def forward_logits(seed: int, cfg: dict, seqs: Sequence[List[int]],
         tok[:len(s)] = s
         xs.append(emb[jnp.asarray(tok)])
         lens.append(len(s))
-    key = W.base_key(seed)
-    for layer in range(cfg["n_layers"]):
-        w = _layer_weights(layer, key, items, control)
-        xs = [block(x, n, w, items) for x, n in zip(xs, lens)]
-        del w
+    for i in range(cfg["n_layers"]):
+        xs = layer(i, xs, lens)
     out = []
     for x, r in zip(xs, rows):
         n = len(r)
@@ -172,15 +147,18 @@ def forward_logits(seed: int, cfg: dict, seqs: Sequence[List[int]],
     return out
 
 
-def served_gaps(seed: int, cfg: dict, requests, control: bool = False):
+def served_gaps(forward, requests, control: bool = False):
     """For each ``(prompt, generated)`` pair: the gap ``max(ref) -
     ref[token]`` of the reference's logits at every served position, for
-    the token the program served there. With ``control=True`` also the
-    gaps of the control's picks (its own first choice at each position,
-    read in the float32 reference), else ``None``: ``(gaps, control)``."""
+    the token the program served there. ``forward(seqs, rows,
+    control=False)`` gives the logits (an architecture's
+    ``forward_logits`` with its seed and sizes bound). With
+    ``control=True`` also the gaps of the control's picks (its own first
+    choice at each position, read in the float32 reference), else
+    ``None``: ``(gaps, control)``."""
     seqs = [list(p) + list(g[:-1]) for p, g in requests]
     rows = [np.arange(len(p) - 1, len(p) - 1 + len(g)) for p, g in requests]
-    ref = forward_logits(seed, cfg, seqs, rows)
+    ref = forward(seqs, rows)
 
     def gaps(picks):
         return [r.max(axis=-1) - np.take_along_axis(r, t[:, None], -1)[:, 0]
@@ -189,5 +167,5 @@ def served_gaps(seed: int, cfg: dict, requests, control: bool = False):
     served = gaps([np.asarray(g) for _, g in requests])
     if not control:
         return served, None
-    ctl = forward_logits(seed, cfg, seqs, rows, control=True)
+    ctl = forward(seqs, rows, control=True)
     return served, gaps([np.argmax(c, axis=-1) for c in ctl])

@@ -35,7 +35,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     sys.path.insert(0, os.path.join(harness.ROOT, "src"))
     spec = harness.load_spec()
-    _, _, tp = harness.load_cell(spec, args.workload)
+    _, _, _, tp = harness.load_cell(spec, args.workload)
     for rate in args.rates:
         t = time.perf_counter()
         tp_r = copy.deepcopy(tp)

@@ -21,7 +21,7 @@ TINY_LIMIT = 0.33
 
 def overrides(cell: str):
     spec = harness.load_spec(ROOT)
-    _, cfg, tp = harness.load_cell(spec, cell, ROOT)
+    _, cfg, _, tp = harness.load_cell(spec, cell, ROOT)
     cfg, tp = copy.deepcopy(cfg), copy.deepcopy(tp)
     gqa = cfg["model"]["n_heads"] // cfg["model"]["n_kv_heads"]
     cfg["model"].update(n_layers=2, d_model=64, n_kv_heads=1,

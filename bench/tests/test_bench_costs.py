@@ -1,5 +1,6 @@
-"""FLOP and byte functions against hand counts at both configurations'
-shapes, and the peaks table."""
+"""FLOP and byte functions (``costs.py`` and the dense architecture's
+counts) against hand counts at both configurations' shapes, and the
+peaks table."""
 
 import json
 import os
@@ -13,10 +14,19 @@ sys.path.insert(0, BENCH)
 import costs  # noqa: E402
 import harness  # noqa: E402
 
+DENSE = harness.load_arch("dense_gqa")
+
 
 def _model(name):
     with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
-        return harness.model_dict(json.load(f))
+        return DENSE.model(json.load(f))
+
+
+def _layer_dims(model):
+    """One layer's weight matmuls: every dense layer runs the same."""
+    (layers, dims), = DENSE.projection_dims(model)
+    assert layers == model["n_layers"]
+    return dims
 
 
 PHI3 = _model("phi3-medium-14b-pp2")
@@ -29,8 +39,8 @@ def test_layer_weights_match_the_published_shapes():
     # phi3-medium: q/o 5120x5120, k/v 5120x1280, gate/up 5120x17920,
     # down 17920x5120; starcoder2-15b: q/o 6144x6144, k/v 6144x512,
     # up 6144x24576, down 24576x6144
-    phi3 = sum(k * n for k, n in costs.projection_dims(PHI3))
-    sc2 = sum(k * n for k, n in costs.projection_dims(SC2))
+    phi3 = sum(k * n for k, n in _layer_dims(PHI3))
+    sc2 = sum(k * n for k, n in _layer_dims(SC2))
     assert phi3 == 2 * 5120 * 5120 + 2 * 5120 * 1280 + 3 * 5120 * 17920
     assert phi3 == 340_787_200
     assert sc2 == 2 * 6144 * 6144 + 2 * 6144 * 512 + 2 * 6144 * 24576
@@ -48,24 +58,23 @@ def test_qmm_counts():
 def test_qmm_over_a_layer(model, per_layer):
     """What ``quant_matmul_roofline`` sums for one layer of one step."""
     rows = 32
-    calls = [costs.qmm(rows, k, n) for k, n in costs.projection_dims(model)]
-    acts = sum(rows * k * 2 + rows * n * 4
-               for k, n in costs.projection_dims(model))
+    calls = [costs.qmm(rows, k, n) for k, n in _layer_dims(model)]
+    acts = sum(rows * k * 2 + rows * n * 4 for k, n in _layer_dims(model))
     assert sum(f for f, _ in calls) == 2 * rows * per_layer
     assert sum(b for _, b in calls) == per_layer + acts
 
 
 def test_paged_attention_counts():
-    f, b = costs.paged_attention(PHI3, [1000, 2000])
+    f, b = DENSE.paged_attention(PHI3, [1000, 2000])
     assert f == 4 * 40 * 128 * 3000 * 20
     assert b == 2 * 10 * 128 * 3000 * 20 + 2 * 2 * 40 * 128 * 4 * 20
-    f, b = costs.paged_attention(SC2, [64])
+    f, b = DENSE.paged_attention(SC2, [64])
     assert f == 4 * 48 * 128 * 64 * 20
     assert b == 2 * 4 * 128 * 64 * 20 + 2 * 48 * 128 * 4 * 20
 
 
 def test_model_flops_counts():
-    f = costs.model_flops(PHI3, [100, 101])
+    f = DENSE.model_flops(PHI3, [100, 101])
     per_tok = 2 * 340_787_200 * 20 + 2 * 5120 * 32064
     assert f == 2 * per_tok + 4 * 40 * 128 * 20 * (100 + 101)
 

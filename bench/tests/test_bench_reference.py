@@ -1,6 +1,6 @@
-"""The plain reference: its takum8 decode and its forward pass, against
-the program at a tiny size on the CPU (float32 compute, no KV format),
-for both activation families."""
+"""The plain reference: its takum8 decode and the dense architecture's
+forward pass, against the program at a tiny size on the CPU (float32
+compute, no KV format), for both activation families."""
 
 import os
 import sys
@@ -12,9 +12,12 @@ import pytest
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, BENCH)
 
+import harness  # noqa: E402
 import reference  # noqa: E402
 import takum8  # noqa: E402
 import weights  # noqa: E402
+
+DENSE = harness.load_arch("dense_gqa")
 
 
 def _table():
@@ -65,14 +68,14 @@ def test_reference_matches_the_program_forward(activation, heads):
                else 1e4)
     mc = ModelConfig(name="tiny", family="dense", dtype="f32",
                      **{k: v for k, v in cfg.items()})
-    params = weights.build_params(3, cfg)
+    params = DENSE.build_params(3, cfg)
     rng = np.random.default_rng(0)
     toks = rng.integers(0, cfg["vocab"], 40)
     with jax.default_matmul_precision("highest"):
         prog, _ = transformer.forward(params, jax.numpy.asarray(toks[None]),
                                         mc)
     rows = np.arange(5, 40)
-    ref = reference.forward_logits(3, cfg, [list(toks)], [rows])[0]
+    ref = DENSE.forward_logits(3, cfg, [list(toks)], [rows])[0]
     got = np.asarray(prog[0, rows])
     scale = np.abs(ref).max()
     assert np.abs(got - ref).max() <= 1e-4 * scale
