@@ -50,14 +50,38 @@ class ModelConfig:
     norm: str = "rmsnorm"
     rope_base: float = 10_000.0
     tie_embeddings: bool = False
-    # MoE
+    # MoE: dropless top-k routing over all ``n_experts``; this device
+    # computes the experts ``[experts_first, experts_first +
+    # experts_held)`` (0 held -> all of them) plus ``n_shared_experts``
+    # always-on experts, each of width ``d_expert`` (0 -> ``d_ff``)
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    experts_first: int = 0
+    experts_held: int = 0
+    d_expert: int = 0
+    n_shared_experts: int = 0
+    # softmax: gates are the softmax of the top-k router logits;
+    # sigmoid: top-k of sigmoid scores plus a per-expert selection bias,
+    # gates the chosen scores normalised to sum 1, times routed_scaling
+    router_scoring: str = "softmax"
+    routed_scaling: float = 1.0
     # hybrid (RecurrentGemma): block pattern, e.g. ("rec", "rec", "attn")
     block_pattern: Tuple[str, ...] = ()
     lru_width: int = 0
     window: int = 0              # sliding-window size for local attention
+    # per-layer kinds as published (empty: every layer alike):
+    # "sliding_attention" (keys within ``window``) | "full_attention",
+    # and "dense" | "sparse" (MoE) feed-forward
+    layer_types: Tuple[str, ...] = ()
+    mlp_layer_types: Tuple[str, ...] = ()
+    # block equations: "pre" norms each sublayer's input, "post" its
+    # output before the residual add; qk_norm RMS-normalises q and k per
+    # head after projection; rope_full=False leaves full_attention
+    # layers without position embedding (NoPE)
+    norm_placement: str = "pre"
+    norm_eps: float = 1e-6
+    qk_norm: bool = False
+    rope_full: bool = True
     # rwkv6
     rwkv_head_dim: int = 64
     # enc-dec
@@ -76,6 +100,16 @@ class ModelConfig:
     # KV-sequence tile for the fused decode-attention kernel
     # (0 -> kernel default; see kernels/takum_attention.py)
     kv_block: int = 0
+
+    def __post_init__(self):
+        # JSON configuration files give lists; a frozen config hashes
+        for name in ("layer_types", "mlp_layer_types", "block_pattern"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        for name in ("layer_types", "mlp_layer_types"):
+            val = getattr(self, name)
+            if val and len(val) != self.n_layers:
+                raise ValueError(f"{name} has {len(val)} entries for "
+                                 f"{self.n_layers} layers")
 
     @property
     def hd(self) -> int:

@@ -23,12 +23,9 @@ CONFIG = ModelConfig(
     top_k=8,
 )
 
-# reduced: capacity_factor = E/k makes dispatch drop-free, so the
-# cache path is bit-comparable with the batched forward in tests
 REDUCED = dataclasses.replace(
     CONFIG, n_layers=3, d_model=64, n_heads=4, n_kv_heads=2, d_ff=64,
-    vocab=512, head_dim=16, n_experts=8, top_k=2, capacity_factor=4.0,
-    dtype="f32")
+    vocab=512, head_dim=16, n_experts=8, top_k=2, dtype="f32")
 
 
 @register_arch("granite-moe-3b-a800m")

@@ -18,14 +18,11 @@ CONFIG = ModelConfig(
     activation="swiglu",
     n_experts=384,
     top_k=8,
-    capacity_factor=1.0,   # at 384e the dispatch buffer dominates; cf=1
 )
 
-# reduced: capacity_factor = E/k = drop-free (see granite config note)
 REDUCED = dataclasses.replace(
     CONFIG, n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=64,
-    vocab=512, head_dim=16, n_experts=16, top_k=4, capacity_factor=4.0,
-    dtype="f32")
+    vocab=512, head_dim=16, n_experts=16, top_k=4, dtype="f32")
 
 
 @register_arch("kimi-k2-1t-a32b")

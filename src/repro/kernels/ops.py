@@ -29,6 +29,7 @@ from repro.kernels import autotune
 from repro.kernels import ref as kref
 from repro.kernels import lns_matmul as klns
 from repro.kernels import takum_attention as kattn
+from repro.kernels import expert_matmul as kexp
 from repro.kernels import takum_codec, takum_matmul, quantize as kquant
 from repro.obs.metrics import GLOBAL
 
@@ -36,7 +37,7 @@ __all__ = ["takum_decode", "takum_encode", "fake_quant_fused", "quant_matmul",
            "lns_matmul", "takum_attention", "paged_attention",
            "interpret_default", "WireMatrix", "default_qmm_blocks",
            "qmm_blocks", "qmm_vmem_bytes", "default_attention_bk",
-           "resolved_blocks"]
+           "resolved_blocks", "ExpertWire", "expert_matmul", "EXPERT_BM"]
 
 
 def interpret_default() -> bool:
@@ -231,9 +232,19 @@ def qmm_blocks(m0: int, k0: int, n0: int, word_bytes: int = 1) -> tuple:
     mp = max(8, -(-m0 // 8) * 8)
     bm = mp if mp <= QMM_ONE_STEP_M else takum_matmul.DEFAULT_BM
     mp = -(-mp // bm) * bm
-    best = (bm, takum_matmul.DEFAULT_BN, takum_matmul.DEFAULT_BK)
-    if mp * best[1] * 4 > takum_matmul.DEFAULT_ACC_BUDGET:
-        return best
+    if mp * takum_matmul.DEFAULT_BN * 4 > takum_matmul.DEFAULT_ACC_BUDGET:
+        return (bm, takum_matmul.DEFAULT_BN, takum_matmul.DEFAULT_BK)
+    return (bm,) + _widest_tile(mp, bm, k0, n0, word_bytes)
+
+
+def _widest_tile(mp: int, bm: int, k0: int, n0: int,
+                 word_bytes: int) -> tuple:
+    """``(bn, bk)``: the multiples of 128 dividing N and K rounded up to
+    128 with the most weight words (ties to the wider ``bn``), at most
+    ``QMM_MAX_TILE_WORDS``, whose ``(mp, bn)`` f32 stripe fits the
+    accumulator budget and whose :func:`qmm_vmem_bytes` fits
+    ``QMM_VMEM_BUDGET``; the 128 tile when none does."""
+    best = (takum_matmul.DEFAULT_BN, takum_matmul.DEFAULT_BK)
     for bk in _tiles_of(k0):
         for bn in _tiles_of(n0):
             if (bk * bn > QMM_MAX_TILE_WORDS
@@ -241,8 +252,8 @@ def qmm_blocks(m0: int, k0: int, n0: int, word_bytes: int = 1) -> tuple:
                     or qmm_vmem_bytes(mp, bm, bk, bn, word_bytes)
                     > QMM_VMEM_BUDGET):
                 continue
-            if (bk * bn, bn) > (best[2] * best[1], best[1]):
-                best = (bm, bn, bk)
+            if (bk * bn, bn) > (best[1] * best[0], best[0]):
+                best = (bn, bk)
     return best
 
 
@@ -646,4 +657,106 @@ class WireMatrix:
 
     def __repr__(self):
         return (f"WireMatrix(shape={tuple(self.words.shape)}, "
+                f"spec={self.spec.name!r})")
+
+
+EXPERT_BM = 32  # rows of one expert's row tile in the grouped matmul
+
+
+def expert_matmul(x, w_words, group, tile_expert, n_used, fmt, *,
+                  bm: int = EXPERT_BM, use_kernel: bool | None = None,
+                  interpret: bool | None = None):
+    """Grouped expert matmul: ``x [M, K]`` rows grouped by expert in
+    ``bm``-row tiles times the decoded wire words ``w_words [E, K, N]``
+    -> ``[M, N]`` f32. Expert ``e`` owns the next ``group[e]`` rows (a
+    whole number of tiles; rows past ``sum(group)`` give zeros), so row
+    tile ``t`` belongs to expert ``tile_expert[t]``, and tiles from
+    ``n_used[0]`` on hold no rows.
+
+    ``use_kernel=True`` runs ``expert_matmul_kernel_call`` (each
+    expert's weight tiles decoded in VMEM through
+    ``FormatSpec.decode_tile``, an expert without rows never read; its
+    oracle is ``kref.expert_matmul_ref``); ``False`` the XLA fallback,
+    the decoded words through ``lax.ragged_dot`` by ``group``; ``None``
+    = kernel on TPU, fallback elsewhere. The kernel's tile ``(bn, bk)``
+    follows ``quant_matmul``'s shape rule (:func:`_widest_tile`) for the
+    whole ``(M, bn)`` output stripe; K and N are zero-padded to it (zero
+    words decode to 0.0)."""
+    spec = _dense_wire_spec(fmt)
+    if use_kernel is None:
+        use_kernel = not interpret_default()
+    if not use_kernel:
+        w = spec.decode_tile(w_words, dtype=jnp.float32)
+        return jax.lax.ragged_dot(x.astype(jnp.float32), w, group,
+                                  precision=jax.lax.Precision.HIGHEST)
+    m, k0 = x.shape
+    n0 = w_words.shape[-1]
+    if m % bm or m * takum_matmul.DEFAULT_BN * 4 > \
+            takum_matmul.DEFAULT_ACC_BUDGET:
+        raise ValueError(f"expert_matmul: {m} rows must be whole {bm}-row "
+                         "tiles whose output stripe fits the accumulator "
+                         "budget; route fewer tokens per call")
+    _, bn, bk = expert_blocks(m, k0, n0, spec.bytes_per_elem())
+    kp, np_ = -(-k0 // bk) * bk, -(-n0 // bn) * bn
+    xp = jnp.pad(x, ((0, 0), (0, kp - k0))) if kp != k0 else x
+    wp = w_words
+    if (kp, np_) != (k0, n0):
+        wp = jnp.pad(w_words, ((0, 0), (0, kp - k0), (0, np_ - n0)))
+    interpret = interpret_default() if interpret is None else interpret
+    out = kexp.expert_matmul_kernel_call(
+        xp, wp, tile_expert, n_used, spec=spec, bm=bm, bn=bn, bk=bk,
+        interpret=interpret)
+    return out[:, :n0]
+
+
+def expert_blocks(m: int, k0: int, n0: int, word_bytes: int = 1) -> tuple:
+    """``(bm, bn, bk)`` of an ``expert_matmul`` kernel call on ``m``
+    grouped rows (what the kernel and its oracle tile by)."""
+    return (EXPERT_BM,) + _widest_tile(m, EXPERT_BM, k0, n0, word_bytes)
+
+
+@jax.tree_util.register_pytree_node_class
+class ExpertWire:
+    """A stack of expert weights ``[..., E, K, N]`` in wire format.
+
+    The words are the pytree leaf, so ``lax.scan`` over a layer-stacked
+    ``[L, E, K, N]`` stack hands each layer an ``ExpertWire`` of its own
+    ``[E, K, N]`` words, as it does a ``WireMatrix``. No float copy of
+    the experts exists: the mixture-of-experts layer passes the words to
+    :func:`expert_matmul`, which decodes each expert's tiles in the
+    kernel."""
+
+    def __init__(self, words, fmt="takum8"):
+        self.spec = formats.resolve_wire(fmt)
+        self.words = words
+
+    @classmethod
+    def encode(cls, w, fmt="takum8"):
+        spec = formats.resolve_wire(fmt)
+        return cls(_encode_words(spec, jnp.asarray(w)), fmt=spec)
+
+    def tree_flatten(self):
+        return (self.words,), self.spec
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], fmt=aux)
+
+    @property
+    def shape(self):
+        return self.words.shape
+
+    @property
+    def ndim(self):
+        return self.words.ndim
+
+    @property
+    def dtype(self):  # decode target dtype, for callers probing params
+        return jnp.float32
+
+    def decode(self, dtype=jnp.float32):
+        return self.spec.decode_tile(self.words, dtype=dtype)
+
+    def __repr__(self):
+        return (f"ExpertWire(shape={tuple(self.words.shape)}, "
                 f"spec={self.spec.name!r})")

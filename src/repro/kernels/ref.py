@@ -29,7 +29,7 @@ from repro import formats
 
 __all__ = ["decode_ref", "encode_ref", "fake_quant_ref", "qmatmul_ref",
            "lns_decode_ref", "fake_quant_lns_ref", "lns_qmatmul_ref",
-           "attention_ref", "paged_attention_ref"]
+           "attention_ref", "paged_attention_ref", "expert_matmul_ref"]
 
 
 def decode_ref(words, fmt, dtype=jnp.float32):
@@ -86,6 +86,38 @@ def lns_qmatmul_ref(x, w_words, fmt, out_dtype=jnp.float32):
     w = spec.decode_tile(w_words)
     return jnp.dot(xq, w,
                    preferred_element_type=jnp.float32).astype(out_dtype)
+
+
+def expert_matmul_ref(x, w_words, tile_expert, n_used, fmt, *, bm: int,
+                      bn: int, bk: int):
+    """Oracle of ``expert_matmul_kernel_call``: row tile ``t`` of ``x [M,
+    K]`` times expert ``tile_expert[t]``'s decoded ``w_words [E, K, N]``,
+    zero from tile ``n_used[0]`` on. Each ``(bm, bk) @ (bk, bn)`` part is
+    computed and summed over ``kk`` in the kernel's order, so the result
+    is bit-identical to the kernel in interpret mode."""
+    spec = formats.resolve(fmt)
+    m, k = x.shape
+    nn = w_words.shape[-1]
+
+    def tile(t):
+        xt = jax.lax.dynamic_slice_in_dim(x, t * bm, bm).astype(jnp.float32)
+        w = w_words[tile_expert[t]]
+        cols = []
+        for j in range(nn // bn):
+            acc = None
+            for kk in range(k // bk):
+                wt = spec.decode_tile(w[kk * bk:(kk + 1) * bk,
+                                        j * bn:(j + 1) * bn],
+                                      dtype=jnp.float32)
+                part = jnp.dot(xt[:, kk * bk:(kk + 1) * bk], wt,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+                acc = part if acc is None else acc + part
+            cols.append(acc)
+        out = jnp.concatenate(cols, axis=1)
+        return jnp.where(t < n_used[0], out, 0.0)
+
+    return jax.lax.map(tile, jnp.arange(m // bm)).reshape(m, nn)
 
 
 def attention_ref(q, k_cache, v_cache, n, fmt="none", *, pos,

@@ -64,9 +64,9 @@ def rope(x, positions, base: float = 10_000.0):
 
 
 def attn_init(key, d_model, n_heads, n_kv_heads, head_dim,
-              dtype=jnp.float32):
+              dtype=jnp.float32, qk_norm: bool = False):
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": dense_init(k1, d_model, n_heads * head_dim, dtype=dtype),
         "wk": dense_init(k2, d_model, n_kv_heads * head_dim, dtype=dtype),
         "wv": dense_init(k3, d_model, n_kv_heads * head_dim, dtype=dtype),
@@ -74,15 +74,22 @@ def attn_init(key, d_model, n_heads, n_kv_heads, head_dim,
                          scale=(1.0 / (n_heads * head_dim)) ** 0.5,
                          dtype=dtype),
     }
+    if qk_norm:
+        p["q_norm"] = rmsnorm_init(head_dim)
+        p["k_norm"] = rmsnorm_init(head_dim)
+    return p
 
 
 def _proj_qkv(params, x, xa, n_heads, n_kv_heads, head_dim, rope_base,
-              positions, use_rope=True):
+              positions, use_rope=True, eps=1e-6):
     b = x.shape[0]
     q = (x @ params["wq"]).reshape(b, -1, n_heads, head_dim)
     src = x if xa is None else xa
     k = (src @ params["wk"]).reshape(b, -1, n_kv_heads, head_dim)
     v = (src @ params["wv"]).reshape(b, -1, n_kv_heads, head_dim)
+    if "q_norm" in params:   # QK-norm: RMSNorm over each head, pre-RoPE
+        q = rmsnorm(params["q_norm"], q, eps)
+        k = rmsnorm(params["k_norm"], k, eps)
     if use_rope and xa is None:
         q = rope(q, positions, rope_base)
         k = rope(k, positions, rope_base)
@@ -214,17 +221,20 @@ KV_ATTN_KERNEL = {"1": True, "0": False}.get(
 
 def attention(params, x, cfg, positions, *, xa=None, mask=None,
               cache: Optional[Dict[str, Any]] = None, window: int = 0,
-              bidirectional: bool = False, prefill_fresh: bool = False):
+              bidirectional: bool = False, prefill_fresh: bool = False,
+              rope: bool = True):
     """Self- or cross-attention with optional decode cache.
 
     cache (self-attn decode): {"k","v": [B, Hkv, Tmax, hd], "pos": scalar},
     or the paged form {"k","v": [P, Hkv, ps, hd], "table": [B, NP],
     "pos"/"start": [B]} built by ``transformer.init_paged_cache``.
-    Returns (out, new_cache).
+    ``rope=False``: no position embedding (a NoPE layer). ``q_norm`` /
+    ``k_norm`` in ``params`` (``cfg.qk_norm``) RMS-normalise each head of
+    q and k after projection. Returns (out, new_cache).
     """
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     q, k, v = _proj_qkv(params, x, xa, h, hkv, hd, cfg.rope_base, positions,
-                        use_rope=xa is None)
+                        use_rope=rope and xa is None, eps=cfg.norm_eps)
     new_cache = None
     if (cache is not None and xa is None and prefill_fresh
             and "start" not in cache

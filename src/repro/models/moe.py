@@ -1,15 +1,36 @@
-"""Token-choice top-k MoE with sort-based static-capacity dispatch.
+"""Dropless top-k mixture of experts over the experts this device holds.
 
-Scales to the 384-expert kimi-k2 config without materialising any
-[tokens, experts] tensor: assignments are sorted by expert id, positions
-within each expert bucket come from a searchsorted over the sorted ids,
-and tokens are scattered into a static [E, C, D] buffer (capacity drop
-semantics). The grouped FFN is a single einsum over the expert dim —
-flop-honest and EP-shardable (E on the "model" mesh axis, capacity rows
-on "data"; GSPMD materialises the dispatch all-to-all from the
-gather/scatter).
+The router scores every token against all ``n_experts`` in float32 and
+picks ``top_k`` of them. This device holds the experts ``[experts_first,
+experts_first + experts_held)`` (all of them when ``experts_held`` is 0,
+as in training) and computes their part of the result; under expert
+parallelism the other chips' experts give the rest, which the exchange
+between them would add and which is left out here. Shared experts run
+for every token.
 
-Aux loss: Switch-style load-balance term, returned to the train loss.
+Dispatch has no capacity and drops nothing: the selected (token, held
+expert) pairs become rows, sorted by expert, each expert's group padded
+to whole ``EXPERT_BM``-row tiles, and the SwiGLU's three projections run
+as grouped matmuls over that layout: ``ops.expert_matmul`` for wire
+stacks (``ops.ExpertWire``: the Pallas kernel decodes each expert's
+weight tiles in VMEM), ``lax.ragged_dot`` for float stacks. Each token's
+result is gathered back from its own rows and summed in the order of its
+selections, so it depends on that token alone, whatever else shares the
+step.
+
+Scoring (``ModelConfig.router_scoring``):
+
+* ``"softmax"``: gates are the softmax of the top-k logits (granite,
+  kimi), with a Switch-style load-balance loss for training;
+* ``"sigmoid"`` (DeepSeek-V3's ``DeepseekV3TopkRouter``, K-EXAONE):
+  ``s = sigmoid(logits)``; the top-k of ``s + router_bias`` are chosen,
+  the bias serving the choice only; gates are the chosen ``s`` over
+  their sum, times ``routed_scaling``. The normalisation counts every
+  chosen expert, held here or not. No auxiliary loss.
+
+Each call also counts the routed rows and the held experts that got any
+(``stats``): the serving counters ``PageStats.moe_rows`` /
+``moe_calls`` (``serve/scheduler.py``).
 """
 
 from __future__ import annotations
@@ -17,105 +38,169 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.dist.sharding import annotate
-from repro.models.layers import dense_init
+from repro.kernels import ops
+from repro.kernels.ops import EXPERT_BM
+from repro.models import layers as L
 
-__all__ = ["moe_init", "moe_apply", "moe_capacity"]
+__all__ = ["moe_init", "moe_apply", "route", "rows_bound", "TOKEN_CHUNK"]
+
+# tokens per grouped matmul: bounds the rows of one call, and with them
+# the kernel's resident output stripe
+TOKEN_CHUNK = 512
 
 
-def moe_capacity(n_tokens: int, n_experts: int, top_k: int,
-                 capacity_factor: float) -> int:
-    c = int(n_tokens * top_k / n_experts * capacity_factor)
-    return max(8, -(-c // 8) * 8)  # round up to a multiple of 8
-
-
-def moe_init(key, d_model, d_ff, n_experts, dtype=jnp.float32):
-    k1, k2, k3, k4 = jax.random.split(key, 4)
+def moe_init(key, d_model, d_expert, n_experts, *, held: int = 0,
+             n_shared: int = 0, bias: bool = False, dtype=jnp.float32):
+    """Router over all ``n_experts``; ``held`` (0 -> all) expert stacks
+    ``experts_{wg,w1,w2}``; a shared SwiGLU of width ``n_shared *
+    d_expert``; with ``bias`` the sigmoid router's selection bias."""
+    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    held = held or n_experts
     scale_in = (1.0 / d_model) ** 0.5
-    scale_out = (1.0 / d_ff) ** 0.5
-    return {
-        "router": dense_init(k1, d_model, n_experts, dtype=jnp.float32),
-        "experts_wg": jax.random.normal(k2, (n_experts, d_model, d_ff),
+    scale_out = (1.0 / d_expert) ** 0.5
+    p = {
+        "router": L.dense_init(k1, d_model, n_experts, dtype=jnp.float32),
+        "experts_wg": jax.random.normal(k2, (held, d_model, d_expert),
                                         dtype) * scale_in,
-        "experts_w1": jax.random.normal(k3, (n_experts, d_model, d_ff),
+        "experts_w1": jax.random.normal(k3, (held, d_model, d_expert),
                                         dtype) * scale_in,
-        "experts_w2": jax.random.normal(k4, (n_experts, d_ff, d_model),
+        "experts_w2": jax.random.normal(k4, (held, d_expert, d_model),
                                         dtype) * scale_out,
     }
+    if bias:
+        p["router_bias"] = jnp.zeros((n_experts,), jnp.float32)
+    if n_shared:
+        p["shared"] = L.mlp_init(k5, d_model, n_shared * d_expert, "swiglu",
+                                 dtype)
+    return p
 
 
-def moe_apply(params, x, *, n_experts: int, top_k: int,
-              capacity_factor: float = 1.25):
-    """x [B, T, D] -> (out [B, T, D], aux_loss scalar).
-
-    **Grouped dispatch**: tokens route within their own group — one group
-    per sequence for train/prefill (so the sort/scatter chain never
-    crosses data shards; GSPMD keeps it shard-local under the batch
-    sharding), and a single whole-batch group for decode (T == 1, where
-    per-sequence buffers would waste E x compute). This mirrors the
-    production pattern (local routing + expert-sharded grouped GEMM).
-    """
-    b, t, d = x.shape
-    if t == 1:
-        g, s = 1, b          # decode: one global group of B tokens
+def route(params, x, cfg):
+    """``x [N, D]`` -> ``(idx [N, k], gates [N, k] f32, aux)``: the
+    chosen experts (global ids) and their gates, in float32 over all
+    ``cfg.n_experts``."""
+    logits = jnp.dot(x.astype(jnp.float32), params["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    k = cfg.top_k
+    if cfg.router_scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + params["router_bias"], k)
+        g = jnp.take_along_axis(s, idx, axis=-1)
+        g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
+        aux = jnp.zeros((), jnp.float32)
+    elif cfg.router_scoring == "softmax":
+        vals, idx = jax.lax.top_k(logits, k)
+        g = jax.nn.softmax(vals, axis=-1)
+        # load-balance aux (Switch): E * mean_e fraction_e * prob_e
+        probs = jax.nn.softmax(logits, axis=-1)
+        top1 = jax.nn.one_hot(idx[:, 0], cfg.n_experts, dtype=jnp.float32)
+        aux = cfg.n_experts * jnp.sum(jnp.mean(top1, axis=0)
+                                      * jnp.mean(probs, axis=0))
     else:
-        g, s = b, t          # train/prefill: per-sequence groups
-    xt = x.reshape(g, s, d)
-    xt = annotate(xt, "batch" if g > 1 else None, None, None)
-    c = moe_capacity(s, n_experts, top_k, capacity_factor)
+        raise ValueError(f"unknown router_scoring {cfg.router_scoring!r}")
+    return idx, g * cfg.routed_scaling, aux
 
-    logits = jnp.einsum("gsd,de->gse", xt,
-                        params["router"].astype(x.dtype)).astype(jnp.float32)
-    gate_vals, idx = jax.lax.top_k(logits, top_k)          # [G, S, k]
-    gates = jax.nn.softmax(gate_vals, axis=-1)
 
-    # load-balance aux (Switch): E * mean_e fraction_e * prob_e
-    probs = jax.nn.softmax(logits, axis=-1)
-    top1 = jax.nn.one_hot(idx[..., 0], n_experts, dtype=jnp.float32)
-    aux = n_experts * jnp.sum(jnp.mean(top1, axis=(0, 1)) *
-                              jnp.mean(probs, axis=(0, 1)))
+def _layout(idx, first: int, held: int, bm: int):
+    """Rows of the held selections, grouped by expert in ``bm``-row tiles.
 
-    def dispatch(xg, idxg, gatesg):
-        """One group: [S,D],[S,k],[S,k] -> buffers + combine metadata."""
-        flat_e = idxg.reshape(-1).astype(jnp.int32)        # [S*k]
-        flat_tok = jnp.repeat(jnp.arange(s, dtype=jnp.int32), top_k)
-        order = jnp.argsort(flat_e)
-        se = flat_e[order]
-        stok = flat_tok[order]
-        sgate = gatesg.reshape(-1)[order]
-        starts = jnp.searchsorted(se, jnp.arange(n_experts,
-                                                 dtype=jnp.int32))
-        pos = jnp.arange(s * top_k, dtype=jnp.int32) - starts[se]
-        keep = pos < c
-        # dropped assignments scatter out-of-bounds (mode="drop"): no
-        # overflow row, so E*c stays cleanly shardable
-        dest = jnp.where(keep, se * c + pos, n_experts * c)
-        buf = jnp.zeros((n_experts * c, d), x.dtype)
-        buf = buf.at[dest].set(xg[stok], mode="drop")
-        return buf.reshape(n_experts, c, d), (stok, sgate, keep, dest)
+    Returns ``(dest [N*k], group [held], tile_expert, n_used, counts)``:
+    the row of each (token, selection) pair in the grouped layout (past
+    the end when the expert is not held here), each expert's padded
+    group size, the expert of each row tile (unused tiles repeat the
+    last used one) and the count of used tiles, and the rows each held
+    expert got."""
+    n, k = idx.shape
+    local = (idx - first).reshape(-1)
+    e = jnp.where((local >= 0) & (local < held), local, held)
+    counts = jnp.bincount(e, length=held + 1)[:held]
+    group = -(-counts // bm) * bm
+    ends = jnp.cumsum(group)
+    order = jnp.argsort(e, stable=True)
+    se = jnp.minimum(e[order], held - 1)
+    rank = jnp.arange(n * k) - (jnp.cumsum(counts) - counts)[se]
+    m = rows_bound(n, k, held, bm)
+    dest_sorted = jnp.where(e[order] < held, ends[se] - group[se] + rank, m)
+    dest = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        dest_sorted.astype(jnp.int32))
+    n_used = (ends[-1] // bm).astype(jnp.int32)
+    tiles = jnp.searchsorted(ends, jnp.arange(m // bm) * bm, side="right",
+                             method="compare_all")
+    last = tiles[jnp.maximum(n_used - 1, 0)]
+    tile_expert = jnp.minimum(
+        jnp.where(jnp.arange(m // bm) < n_used, tiles, last), held - 1)
+    return (dest, group.astype(jnp.int32), tile_expert.astype(jnp.int32),
+            n_used.reshape(1), counts)
 
-    buf, meta = jax.vmap(dispatch)(xt, idx, gates)         # [G,E,C,D]
-    buf = annotate(buf, "batch" if g > 1 else None, "experts", None, None)
 
-    # ---- grouped SwiGLU FFN (expert dim sharded over "model") -----------
-    wg, w1, w2 = (params["experts_wg"].astype(x.dtype),
-                  params["experts_w1"].astype(x.dtype),
-                  params["experts_w2"].astype(x.dtype))
-    hid = jax.nn.silu(jnp.einsum("gecd,edf->gecf", buf, wg)) * \
-        jnp.einsum("gecd,edf->gecf", buf, w1)
-    hid = annotate(hid, "batch" if g > 1 else None, "experts", None, "ff")
-    out_buf = jnp.einsum("gecf,efd->gecd", hid, w2)
-    out_buf = annotate(out_buf, "batch" if g > 1 else None, "experts",
-                       None, None)
+def rows_bound(n: int, k: int, held: int, bm: int) -> int:
+    """Rows of the grouped layout for ``n`` tokens: every selection held
+    here, and each expert's last tile partly filled."""
+    return -(-(n * min(k, held) + held * (bm - 1)) // bm) * bm
 
-    def combine(out_g, m):
-        stok, sgate, keep, dest = m
-        flat = out_g.reshape(n_experts * c, d)
-        y = jnp.where(keep[:, None],
-                      flat[jnp.minimum(dest, n_experts * c - 1)], 0.0)
-        y = y * sgate[:, None].astype(x.dtype)
-        return jnp.zeros((s, d), x.dtype).at[stok].add(y)
 
-    out = jax.vmap(combine)(out_buf, meta)                 # [G,S,D]
-    out = annotate(out, "batch" if g > 1 else None, None, None)
-    return out.reshape(b, t, d), aux.astype(jnp.float32)
+def _grouped(xs, w, group, tile_expert, n_used):
+    """``xs [M, K]`` grouped rows times their expert's ``w`` -> ``[M, N]``
+    in ``xs``'s dtype."""
+    if isinstance(w, ops.ExpertWire):
+        out = ops.expert_matmul(xs, w.words, group, tile_expert, n_used,
+                                w.spec, bm=EXPERT_BM)
+    else:
+        out = jax.lax.ragged_dot(xs, w.astype(xs.dtype), group)
+    return out.astype(xs.dtype)
+
+
+def _routed(params, x, idx, g, first: int, held: int):
+    """The held experts' part for ``x [n, D]``: ``(y [n, D], rows,
+    calls)``."""
+    n, d = x.shape
+    k = idx.shape[1]
+    with jax.named_scope("moe.route"):
+        dest, group, tile_expert, n_used, counts = _layout(idx, first, held,
+                                                           EXPERT_BM)
+        m = rows_bound(n, k, held, EXPERT_BM)
+        xs = jnp.zeros((m, d), x.dtype).at[dest].set(
+            jnp.repeat(x, k, axis=0), mode="drop")
+    with jax.named_scope("moe.experts"):
+        h = jax.nn.silu(_grouped(xs, params["experts_wg"], group,
+                                 tile_expert, n_used)) \
+            * _grouped(xs, params["experts_w1"], group, tile_expert, n_used)
+        out = _grouped(h, params["experts_w2"], group, tile_expert, n_used)
+        rows = out[jnp.minimum(dest, m - 1)].reshape(n, k, d)
+        wt = jnp.where((dest < m).reshape(n, k), g, 0.0)
+        y = jnp.sum(wt[..., None] * rows.astype(jnp.float32), axis=1)
+    return (y.astype(x.dtype), jnp.sum(counts).astype(jnp.int32),
+            jnp.sum(counts > 0).astype(jnp.int32))
+
+
+def moe_apply(params, x, cfg):
+    """x [B, T, D] -> (out [B, T, D], aux_loss, stats): the shared
+    experts plus the gated outputs of the held experts each token chose;
+    ``stats`` = ``{"moe_rows", "moe_calls"}`` int32 (routed rows, and
+    held experts with rows, summed over the token chunks)."""
+    b, t, d = x.shape
+    x2 = x.reshape(b * t, d)
+    first, held = cfg.experts_first, cfg.experts_held or cfg.n_experts
+    with jax.named_scope("moe.route"):
+        idx, g, aux = route(params, x2, cfg)
+    n = b * t
+    if n <= TOKEN_CHUNK:
+        y, rows, calls = _routed(params, x2, idx, g, first, held)
+    else:
+        pad = -n % TOKEN_CHUNK
+        chunks = [jnp.pad(a, ((0, pad), (0, 0))).reshape(
+            -1, TOKEN_CHUNK, a.shape[1]) for a in (x2, idx, g)]
+        # padding tokens hold no rows: their selections point past the
+        # held range
+        valid = (jnp.arange(n + pad) < n).reshape(-1, TOKEN_CHUNK, 1)
+        chunks[1] = jnp.where(valid, chunks[1], first + held)
+        y, rows, calls = jax.lax.map(
+            lambda c: _routed(params, c[0], c[1], c[2], first, held),
+            tuple(chunks))
+        y = y.reshape(-1, d)[:n]
+        rows, calls = jnp.sum(rows), jnp.sum(calls)
+    if "shared" in params:
+        with jax.named_scope("moe.shared"):
+            y = y + L.mlp(params["shared"], x2, "swiglu")
+    return (y.reshape(b, t, d), aux.astype(jnp.float32),
+            {"moe_rows": rows, "moe_calls": calls})

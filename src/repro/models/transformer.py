@@ -38,20 +38,25 @@ def cast_params(params, dtype):
       any FSDP gathers along the way carry n/32 of the f32 bytes. This is
       the codec-as-matmul-input-stage integration on the XLA path (the
       Pallas kernel fuses the same decode into the matmul tile loop);
-    * ``WireMatrix`` nodes (serve.engine ``mode="wire"``) pass through
-      untouched: their words must *stay* words so each ``x @ w`` site
-      routes through the decode-once weight-stationary matmul instead of
-      an eager whole-tensor decode.
+    * ``WireMatrix`` and ``ExpertWire`` nodes (serve.engine
+      ``mode="wire"``) pass through untouched: their words must *stay*
+      words so each ``x @ w`` site routes through the decode-once
+      weight-stationary matmul, and each expert stack through the
+      grouped expert matmul, instead of an eager whole-tensor decode;
+    * MoE router weights and selection bias stay float32.
     """
     from repro.core import takum as _takum
-    from repro.kernels.ops import WireMatrix
+    from repro.kernels.ops import ExpertWire, WireMatrix
 
     def is_wire(p):
-        return isinstance(p, WireMatrix)
+        return isinstance(p, (WireMatrix, ExpertWire))
 
-    def cast(p):
+    def cast(path, p):
         if is_wire(p):
             return p
+        if any(getattr(k, "key", None) in ("router", "router_bias")
+               for k in path):
+            return p          # MoE routing runs in float32
         if hasattr(p, "dtype"):
             if p.dtype in (jnp.uint8, jnp.uint16):
                 n = jnp.iinfo(p.dtype).bits
@@ -59,7 +64,7 @@ def cast_params(params, dtype):
             if jnp.issubdtype(p.dtype, jnp.floating):
                 return p.astype(dtype)
         return p
-    return jax.tree_util.tree_map(cast, params, is_leaf=is_wire)
+    return jax.tree_util.tree_map_with_path(cast, params, is_leaf=is_wire)
 
 
 # ---------------------------------------------------------------------------
@@ -67,18 +72,29 @@ def cast_params(params, dtype):
 # ---------------------------------------------------------------------------
 
 
+LOCAL = ":local"   # kind suffix of a sliding-window attention layer
+
+
+def _base(kind: str) -> str:
+    return kind.split(":")[0]
+
+
 def _block_init(key, cfg: ModelConfig, kind: str, dtype):
     k1, k2, k3, k4 = jax.random.split(key, 4)
     d = cfg.d_model
     p: Dict[str, Any] = {"ln1": L.rmsnorm_init(d), "ln2": L.rmsnorm_init(d)}
+    kind = _base(kind)
     if kind in ("self", "cross"):
         p["attn"] = L.attn_init(k1, d, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-                                dtype)
+                                dtype, qk_norm=cfg.qk_norm)
         p["mlp"] = L.mlp_init(k2, d, cfg.d_ff, cfg.activation, dtype)
     elif kind == "moe":
         p["attn"] = L.attn_init(k1, d, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-                                dtype)
-        p["moe"] = moe_mod.moe_init(k2, d, cfg.d_ff, cfg.n_experts, dtype)
+                                dtype, qk_norm=cfg.qk_norm)
+        p["moe"] = moe_mod.moe_init(
+            k2, d, cfg.d_expert or cfg.d_ff, cfg.n_experts,
+            held=cfg.experts_held, n_shared=cfg.n_shared_experts,
+            bias=cfg.router_scoring == "sigmoid", dtype=dtype)
     elif kind == "rec":
         p["rec"] = rg.rglru_block_init(k1, d, cfg.lru_width or d, dtype)
         p["mlp"] = L.mlp_init(k2, d, cfg.d_ff, cfg.activation, dtype)
@@ -96,6 +112,8 @@ def _block_apply(p, x, cfg: ModelConfig, kind: str, positions, *,
                  prefill_fresh=False):
     """returns (x, aux, new_cache)."""
     aux = jnp.zeros((), jnp.float32)
+    rope = kind.endswith(LOCAL) or cfg.rope_full
+    kind = _base(kind)
     if kind == "rwkv":
         h, st = rk.rwkv_time_mix(p["blk"], L.rmsnorm(p["ln1"], x),
                                  cfg.rwkv_head_dim,
@@ -121,23 +139,32 @@ def _block_apply(p, x, cfg: ModelConfig, kind: str, positions, *,
         x = x + h
         x = x + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], x), cfg.activation)
         return x, aux, cache  # cross KV is position-independent; cache unused
-    # self / moe
-    h, newattn = L.attention(p["attn"], L.rmsnorm(p["ln1"], x), cfg,
-                             positions, mask=mask,
+    # self / moe: "pre" normalises each sublayer's input, "post" its
+    # output before the residual add (EXAONE-4's post_attention_ and
+    # post_feedforward_layernorm)
+    pre = cfg.norm_placement == "pre"
+
+    def norm(ln, h):
+        return L.rmsnorm(ln, h, cfg.norm_eps)
+
+    h, newattn = L.attention(p["attn"], norm(p["ln1"], x) if pre else x,
+                             cfg, positions, mask=mask,
                              cache=None if cache is None else cache["attn"],
-                             window=window, prefill_fresh=prefill_fresh)
-    x = x + h
+                             window=window, prefill_fresh=prefill_fresh,
+                             rope=rope)
+    x = x + (h if pre else norm(p["ln1"], h))
+    hin = norm(p["ln2"], x) if pre else x
     if kind == "moe":
-        h, aux = moe_mod.moe_apply(p["moe"], L.rmsnorm(p["ln2"], x),
-                                   n_experts=cfg.n_experts, top_k=cfg.top_k,
-                                   capacity_factor=cfg.capacity_factor)
+        h, aux, stats = moe_mod.moe_apply(p["moe"], hin, cfg)
     else:
         # thread the attn cache dict through the MLP's TP seam so its
         # error-feedback residual (tp_res_m) rides the same scan carry
-        h, newattn = L.mlp_tp(p["mlp"], L.rmsnorm(p["ln2"], x),
-                              cfg.activation, newattn)
-    x = x + h
+        h, newattn = L.mlp_tp(p["mlp"], hin, cfg.activation, newattn)
+    x = x + (h if pre else norm(p["ln2"], h))
     newc = None if cache is None else {"attn": newattn}
+    if cache is not None and "moe" in cache:
+        # the paged cache's per-layer routing counters of this step
+        newc["moe"] = jnp.stack([stats["moe_rows"], stats["moe_calls"]])
     return x, aux, newc
 
 
@@ -149,6 +176,8 @@ def _block_apply(p, x, cfg: ModelConfig, kind: str, positions, *,
 def layer_plan(cfg: ModelConfig):
     """Returns a list of (scan_kinds: tuple, n_repeat). Each group is a
     super-block of len(scan_kinds) layers, repeated n_repeat times by scan."""
+    if cfg.layer_types or cfg.mlp_layer_types:
+        return _typed_plan(cfg)
     if cfg.family == "rwkv6":
         return [(("rwkv",), cfg.n_layers)]
     if cfg.family == "hybrid_rglru":
@@ -169,8 +198,44 @@ def layer_plan(cfg: ModelConfig):
     return [((kind,), cfg.n_layers)]
 
 
+def _typed_plan(cfg: ModelConfig):
+    """The plan of published per-layer types: each layer is ``"moe"``
+    (``mlp_layer_types`` "sparse") or ``"self"`` (dense), with
+    :data:`LOCAL` for ``layer_types`` "sliding_attention". A run of
+    layers with one feed-forward type is scanned over whole periods of
+    the attention pattern (the smallest ``p`` with ``type[i] ==
+    type[i % p]``, aligned to layer 0), with the layers before the
+    run's first period boundary and after its last whole period as
+    groups of their own: K-EXAONE's layers 0-7 are dense ``L``, then
+    MoE ``L, L, G``, then MoE ``L, L, L, G``."""
+    n = cfg.n_layers
+    attn = cfg.layer_types or ("full_attention",) * n
+    mlp = cfg.mlp_layer_types or \
+        (("sparse" if cfg.family == "moe" else "dense"),) * n
+    kinds = [("moe" if m == "sparse" else "self")
+             + (LOCAL if a == "sliding_attention" else "")
+             for a, m in zip(attn, mlp)]
+    period = next(p for p in range(1, n + 1)
+                  if all(attn[i] == attn[i % p] for i in range(n)))
+    plan, a = [], 0
+    while a < n:
+        b = a
+        while b < n and mlp[b] == mlp[a]:
+            b += 1
+        head = min(b, -(-a // period) * period)
+        reps = (b - head) // period
+        tail = head + reps * period
+        for lo, hi, rep in ((a, head, 1), (head, head + period, reps),
+                            (tail, b, 1)):
+            if hi > lo and rep:
+                plan.append((tuple(kinds[lo:hi]), rep))
+        a = b
+    return plan
+
+
 def _window_for(cfg: ModelConfig, kind: str) -> int:
-    if cfg.family == "hybrid_rglru" and kind == "self":
+    if kind.endswith(LOCAL) or (cfg.family == "hybrid_rglru"
+                                and kind == "self"):
         return cfg.window
     return 0
 
@@ -206,7 +271,8 @@ def init(key, cfg: ModelConfig):
 
 def _run_groups(params, x, cfg, positions, *, mask, media, caches, remat,
                 windows_needed=True, prefill_fresh=False):
-    """Scan each (super-block) group; returns (x, aux_total, new_caches)."""
+    """Scan each (super-block) group; returns (x, aux_total, new_caches).
+    ``mask`` is ``None`` or a function of a layer's window."""
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = []
     for gi, (pat, n_rep) in enumerate(layer_plan(cfg)):
@@ -218,12 +284,13 @@ def _run_groups(params, x, cfg, positions, *, mask, media, caches, remat,
             aux_sb = jnp.zeros((), jnp.float32)
             newc = {}
             for i, kind in enumerate(pat):
+                window = _window_for(cfg, kind)
                 x, aux_i, nc = _block_apply(
-                    bparams[f"b{i}"], x, cfg, kind, positions, mask=mask,
+                    bparams[f"b{i}"], x, cfg, kind, positions,
+                    mask=None if mask is None else mask(window),
                     media=media,
                     cache=None if bcache is None else bcache[f"b{i}"],
-                    window=_window_for(cfg, kind),
-                    prefill_fresh=prefill_fresh)
+                    window=window, prefill_fresh=prefill_fresh)
                 aux_sb = aux_sb + aux_i
                 if nc is not None:
                     newc[f"b{i}"] = nc
@@ -278,12 +345,12 @@ def forward(params, tokens, cfg: ModelConfig, *, media=None,
     if cfg.family not in ("rwkv6",) and t < L.ATTN_CHUNK_T:
         # long sequences use the chunked path (builds its own band masks);
         # materialising a [T, T] mask at 32k+ would itself blow memory
-        win = cfg.window if cfg.family == "hybrid_rglru" else 0
-        mask = L.causal_mask(t, t, window=win)
+        def mask(window):
+            return L.causal_mask(t, t, window=window)
     media = _prep_media(params, media, dtype)
     x, aux, _ = _run_groups(params, x, cfg, positions, mask=mask,
                             media=media, caches=None, remat=remat)
-    x = L.rmsnorm(params["final_norm"], x)
+    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if features:
         return x[:, :t_orig], aux
     logits = L.unembed(params, x, vocab=cfg.vocab)
@@ -308,6 +375,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         def one_cache():
             c = {}
             for i, kind in enumerate(pat):
+                kind = _base(kind)
                 if kind in ("self", "moe"):
                     # head-major [B, Hkv, T, hd]: a KV tile of one head
                     # is a contiguous (T, hd) slab for the fused kernel
@@ -355,7 +423,7 @@ def paged_supported(cfg: ModelConfig) -> bool:
     """
     if cfg.family == "encdec":
         return False
-    return all(kind in ("self", "moe")
+    return all(_base(kind) in ("self", "moe")
                for pat, _ in layer_plan(cfg) for kind in pat)
 
 
@@ -387,8 +455,14 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_pages: int,
     for pat, n_rep in layer_plan(cfg):
         def one_cache():
             c = {}
-            for i, _kind in enumerate(pat):
-                c[f"b{i}"] = {"attn": {
+            for i, kind in enumerate(pat):
+                if _base(kind) == "moe":
+                    # routed rows and expert calls of the last step
+                    # (summed by the scheduler, PageStats.moe_rows)
+                    c[f"b{i}"] = {"moe": jnp.zeros((2,), jnp.int32)}
+                else:
+                    c[f"b{i}"] = {}
+                c[f"b{i}"]["attn"] = {
                     "k": jnp.zeros((num_pages, cfg.n_kv_heads, page_size,
                                     cfg.hd), kv_dtype),
                     "v": jnp.zeros((num_pages, cfg.n_kv_heads, page_size,
@@ -396,7 +470,7 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_pages: int,
                     "table": jnp.zeros((batch, max_pages), jnp.int32),
                     "pos": jnp.zeros((batch,), jnp.int32),
                     "start": jnp.zeros((batch,), jnp.int32),
-                }}
+                }
             return c
 
         stacked = jax.tree_util.tree_map(
@@ -430,7 +504,7 @@ def forward_cached(params, tokens, cfg: ModelConfig, caches, *, pos,
     x, _, new_caches = _run_groups(params, x, cfg, positions, mask=None,
                                    media=media, caches=caches, remat=False,
                                    prefill_fresh=t > 1)
-    x = L.rmsnorm(params["final_norm"], x)
+    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if last_only:
         logits = L.unembed(params, x[:, t_orig - 1:t_orig], vocab=cfg.vocab)
     else:

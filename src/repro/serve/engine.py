@@ -78,7 +78,8 @@ def sample_rows(logits, keys, temps, top_ps):
     return jax.vmap(one)(logits, keys, temps, top_ps)
 
 
-_DEFAULT_SKIP = ("embed", "unembed", "scale", "norm")
+# MoE routing (router weights and selection bias) stays float32
+_DEFAULT_SKIP = ("embed", "unembed", "scale", "norm", "router")
 
 # per-format largest finite magnitude, cached by name: decode every word
 # of the format once and mask the non-finite (NaR) entry. The identity
@@ -133,8 +134,11 @@ def quantize_weights(params, fmt: str = "takum8", *,
     sqrt(e)^±255 range needs no scale side-channel). Only leaves on the
     ``wire_leaves`` allowlist below are wired — every name on it is
     consumed via a plain ``x @ w`` across all model families (attention
-    and MLP projections, rwkv mixer/gate matrices); anything else —
-    einsum'd matrices (MoE ``experts_*`` stacks), lora factors, skipped
+    and MLP projections, rwkv mixer/gate matrices) — and the MoE expert
+    stacks ``experts_{wg,w1,w2}`` (``[E, K, N]``, or ``[L, E, K, N]``
+    layer-stacked), which become :class:`repro.kernels.ops.ExpertWire`
+    words for the grouped expert matmul (float formats only: the LNS
+    formats fake-quantise them); anything else — lora factors, skipped
     names, unknown new projections — falls back to in-place fake-quant,
     trading the wire saving for guaranteed compatibility.
 
@@ -160,6 +164,7 @@ def quantize_weights(params, fmt: str = "takum8", *,
     # exact leaf names applied via `x @ w` (matmul defers to WireMatrix);
     # other matrices go through einsum sites that need real arrays
     wire_leaves = {"wq", "wk", "wv", "wo", "wg", "wr", "w1", "w2"}
+    expert_leaves = {"experts_wg", "experts_w1", "experts_w2"}
     counts = {"wired": 0, "fake": 0, "skipped": 0, "non_matrix": 0}
     matched: set = set()
     # numeric-health telemetry (REPRO_OBS only): count weights whose
@@ -199,6 +204,10 @@ def quantize_weights(params, fmt: str = "takum8", *,
         if mode == "wire" and named and leaf.ndim in (2, 3):
             counts["wired"] += 1
             return kops.WireMatrix.encode(leaf, fmt=spec)
+        if (mode == "wire" and parts[-1] in expert_leaves
+                and leaf.ndim in (3, 4) and not spec.has_lns_parts):
+            counts["wired"] += 1
+            return kops.ExpertWire.encode(leaf, fmt=spec)
         counts["fake"] += 1
         # the spec's fake-quant policy: per-tensor power-of-two centring
         # for linear takum, unscaled grid round trip for LNS/posit

@@ -128,6 +128,11 @@ class PageStats:
     # (a shared page counts once per row); their ratio is the pool's fill
     fill_tokens: int
     fill_capacity: int
+    # summed over decode steps of a mixture-of-experts model
+    # (``note_experts``): routed (token, held expert) rows, and held
+    # experts that got rows, over every MoE layer
+    moe_rows: int = 0
+    moe_calls: int = 0
 
 
 class PagePool:
@@ -164,6 +169,8 @@ class PagePool:
         self._prefix_hit_tokens = 0
         self._fill_tokens = 0
         self._fill_capacity = 0
+        self._moe_rows = 0
+        self._moe_calls = 0
         # pages given to each slot by assign (0 for an idle slot)
         self._slot_pages = [0] * batch
         # host mirrors of the per-slot table state (pushed on change)
@@ -349,7 +356,27 @@ class PagePool:
                          prefix_hit_tokens=self._prefix_hit_tokens,
                          quarantined=self.pages_quarantined(),
                          fill_tokens=self._fill_tokens,
-                         fill_capacity=self._fill_capacity)
+                         fill_capacity=self._fill_capacity,
+                         moe_rows=self._moe_rows,
+                         moe_calls=self._moe_calls)
+
+    def note_experts(self) -> tuple:
+        """Add the last decode step's MoE counters, which the step wrote
+        into each MoE layer's ``"moe"`` leaf of the cache (routed rows,
+        expert calls), to ``PageStats.moe_rows`` / ``moe_calls``; returns
+        the step's ``(rows, calls)``. Read after the step's tokens, so the
+        counters are ready; a model without MoE layers has no leaf and
+        reads nothing."""
+        rows = calls = 0
+        for group in self.cache or ():
+            for bname in sorted(group):
+                node = group[bname]
+                if isinstance(node, dict) and "moe" in node:
+                    r, c = np.asarray(node["moe"]).sum(axis=0)
+                    rows, calls = rows + int(r), calls + int(c)
+        self._moe_rows += rows
+        self._moe_calls += calls
+        return rows, calls
 
     # -- block tables ------------------------------------------------------
 

@@ -945,6 +945,11 @@ class Scheduler:
             # block-table pages fed corruption into its logits — poison
             # exactly it
             bad_rows = np.asarray(bad)
+            # the step's MoE counters, computed with the tokens just read
+            rows, calls = self.pool.note_experts()
+        if calls and self.obs is not None:
+            self.obs.metrics.counter("moe.rows").inc(rows)
+            self.obs.metrics.counter("moe.calls").inc(calls)
         with phase("decode_commit", self._tracer):
             events = self._commit_decode(active, toks, bad_rows)
         yield from events

@@ -155,3 +155,25 @@ def test_seeded_tpu_table_blocks_lower(one_chip, key):
     _compile(one_chip, lambda x, w: fn(x, w, spec, True, False, blocks),
              ((int(m), int(k)), jnp.bfloat16),
              ((int(k), int(n)), spec.word_dtype))
+
+
+# K-EXAONE-236B-A23B's expert layer: d_model 6144, experts of 2048, 16
+# experts held, top-8 of 128 routed; the grouped rows of a 128-row decode
+# step and of a 256-row prefill chunk
+EXP_D, EXP_FF, EXP_HELD, EXP_TOPK = 6144, 2048, 16, 8
+
+
+@pytest.mark.parametrize("tokens", [128, 256])
+@pytest.mark.parametrize("k,n", [(EXP_D, EXP_FF), (EXP_FF, EXP_D)])
+def test_expert_matmul_lowers(one_chip, tokens, k, n):
+    from repro.models.moe import rows_bound
+    m = rows_bound(tokens, EXP_TOPK, EXP_HELD, ops.EXPERT_BM)
+    spec = formats.get("takum8")
+    txt = _compile(
+        one_chip,
+        lambda x, w, g, te, nu: ops.expert_matmul(
+            x, w, g, te, nu, spec, use_kernel=True, interpret=False),
+        ((m, k), jnp.bfloat16), ((EXP_HELD, k, n), jnp.uint8),
+        ((EXP_HELD,), jnp.int32), ((m // ops.EXPERT_BM,), jnp.int32),
+        ((1,), jnp.int32))
+    assert "expert_matmul" in txt
