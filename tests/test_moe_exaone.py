@@ -104,7 +104,7 @@ def test_a_tokens_result_is_its_own(wire):
     alone = np.concatenate([np.asarray(f(p, x[:, i:i + 1]))
                             for i in range(24)], axis=1)
     np.testing.assert_array_equal(whole, alone)
-    # the same token among other batch-mates, and all tokens on one expert
+    # the same token beside batch-mates that all route alike
     mates = jnp.concatenate([x[:, :1], jnp.zeros_like(x[:, 1:])], axis=1)
     np.testing.assert_array_equal(np.asarray(f(p, mates))[:, 0], whole[:, 0])
 
@@ -219,3 +219,20 @@ def test_decode_rows_do_not_change_with_batch_mates():
     a = step(params, jnp.asarray([[7], [1], [2], [3]]), cache, pos)
     b = step(params, jnp.asarray([[7], [200], [9], [77]]), cache, pos)
     np.testing.assert_array_equal(np.asarray(a)[0], np.asarray(b)[0])
+
+
+def test_more_tokens_than_one_grouped_call_route_in_chunks():
+    """Past ``TOKEN_CHUNK`` tokens the layer routes chunk by chunk (its
+    padding tokens take no rows); each token's result is still its own,
+    and the counters add up over the chunks."""
+    cfg = tiny()
+    p = _wire(_moe_params(cfg))
+    n = moe.TOKEN_CHUNK + 88
+    x = jax.random.normal(jax.random.PRNGKey(6), (1, n, cfg.d_model))
+    f = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg))
+    whole, _, stats = f(p, x)
+    half = n // 2
+    (a, _, sa), (b, _, sb) = f(p, x[:, :half]), f(p, x[:, half:])
+    np.testing.assert_array_equal(np.asarray(whole),
+                                  np.concatenate([a, b], axis=1))
+    assert int(stats["moe_rows"]) == int(sa["moe_rows"]) + int(sb["moe_rows"])
