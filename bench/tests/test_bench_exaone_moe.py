@@ -19,9 +19,10 @@ import costs
 import harness
 
 CELL = "kexaone-overload"
-# at these sizes sound runs read 0.0 (every served token the reference's
-# first choice) and the int4 control 1.31-3.04 (CPU, seeds 1-3): the
-# limit sits between
+# at these sizes sound runs read 0.33-0.40 in bfloat16, the cell's
+# precision (0.0 in float32: every served token the reference's first
+# choice), and the int4 control 1.31-3.04 (CPU, seeds 1-3): the limit
+# sits between
 TINY_LIMIT = 0.5
 
 
@@ -108,15 +109,15 @@ def test_counts_at_published_widths():
 def test_reference_matches_the_paged_program():
     """Chunked prefill on a contiguous cache, then paged decode past the
     window, against the reference's full forward. Both hold K and V as
-    takum8 words and compute in float32 (the configuration's
-    activations), so only the f32 summation order differs: measured
+    takum8 words and compute in float32 (the program's float32 path, not
+    the cell's bfloat16), so only the f32 summation order differs: measured
     <= 3e-6 on logits of magnitude ~3.5; 1e-4 leaves room for another
     order of the sums and none for a takum8 rounding step (~4%)."""
     from repro.configs.base import ModelConfig
     from repro.models import model as M
     from repro.serve.paged import PagePool
     _, _, arch, _ = _cell()
-    cfg = tiny_cfg()
+    cfg = tiny_cfg(dtype="f32")
     m = arch.model(cfg)
     mc = ModelConfig(**cfg["model"])
     params = arch.build_params(3, m)

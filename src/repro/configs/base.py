@@ -126,7 +126,13 @@ class ModelConfig:
         else:
             mlp = 2 * d * ff
         if self.family == "moe":
-            mlp = self.n_experts * (3 * d * ff) + d * self.n_experts
+            # every expert of the model, held here or not; the dense
+            # layers of ``mlp_layer_types`` keep the plain MLP
+            n_sparse = self._n_sparse()
+            total = self.n_layers * (attn + 2 * d) \
+                + n_sparse * self._moe_params(self.n_experts) \
+                + (self.n_layers - n_sparse) * mlp
+            return total + V * d * (1 if self.tie_embeddings else 2)
         per_layer = attn + mlp + 2 * d
         if self.family == "rwkv6":
             # time-mix ~ 5 d^2-ish projections + decay MLPs, channel-mix 2*d*ff
@@ -154,12 +160,25 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: top_k of n_experts)."""
+        """Active params per token (MoE: top_k of n_experts, the shared
+        experts and the router in each sparse layer)."""
         if self.family != "moe":
             return self.param_count()
-        d, ff = self.d_model, self.d_ff
-        dense = self.param_count() - self.n_layers * self.n_experts * 3 * d * ff
-        return dense + self.n_layers * self.top_k * 3 * d * ff
+        return self.param_count() - self._n_sparse() * (
+            self._moe_params(self.n_experts) - self._moe_params(self.top_k))
+
+    def _n_sparse(self) -> int:
+        """MoE layers: those ``mlp_layer_types`` names sparse, else all."""
+        if self.mlp_layer_types:
+            return sum(t == "sparse" for t in self.mlp_layer_types)
+        return self.n_layers
+
+    def _moe_params(self, n_routed: int) -> int:
+        """One MoE layer's feed-forward with ``n_routed`` routed SwiGLU
+        experts, the shared experts and the router."""
+        d = self.d_model
+        return (n_routed + self.n_shared_experts) * 3 * d \
+            * (self.d_expert or self.d_ff) + d * self.n_experts
 
     def _block_kind(self, i: int) -> str:
         if not self.block_pattern:

@@ -39,7 +39,6 @@ RULES_2D: Dict[str, Axes] = {
     "kv_heads": ("model",),
     "ff": ("model",),
     "vocab": ("model",),
-    "experts": ("model",),
     "state": ("model",),
 }
 

@@ -663,32 +663,22 @@ class WireMatrix:
 EXPERT_BM = 32  # rows of one expert's row tile in the grouped matmul
 
 
-def expert_matmul(x, w_words, group, tile_expert, n_used, fmt, *,
-                  bm: int = EXPERT_BM, use_kernel: bool | None = None,
-                  interpret: bool | None = None):
+def expert_matmul(x, w_words, tile_expert, n_used, fmt, *,
+                  bm: int = EXPERT_BM, interpret: bool | None = None):
     """Grouped expert matmul: ``x [M, K]`` rows grouped by expert in
     ``bm``-row tiles times the decoded wire words ``w_words [E, K, N]``
-    -> ``[M, N]`` f32. Expert ``e`` owns the next ``group[e]`` rows (a
-    whole number of tiles; rows past ``sum(group)`` give zeros), so row
-    tile ``t`` belongs to expert ``tile_expert[t]``, and tiles from
-    ``n_used[0]`` on hold no rows.
+    -> ``[M, N]`` f32. Row tile ``t`` belongs to expert
+    ``tile_expert[t]``, and tiles from ``n_used[0]`` on hold no rows
+    (they give zeros).
 
-    ``use_kernel=True`` runs ``expert_matmul_kernel_call`` (each
-    expert's weight tiles decoded in VMEM through
-    ``FormatSpec.decode_tile``, an expert without rows never read; its
-    oracle is ``kref.expert_matmul_ref``); ``False`` the XLA fallback,
-    the decoded words through ``lax.ragged_dot`` by ``group``; ``None``
-    = kernel on TPU, fallback elsewhere. The kernel's tile ``(bn, bk)``
-    follows ``quant_matmul``'s shape rule (:func:`_widest_tile`) for the
-    whole ``(M, bn)`` output stripe; K and N are zero-padded to it (zero
-    words decode to 0.0)."""
+    Runs ``expert_matmul_kernel_call``: each expert's weight tiles are
+    decoded in VMEM through ``FormatSpec.decode_tile``, and an expert
+    without rows is never read; its oracle is ``kref.expert_matmul_ref``
+    (``interpret=None`` = interpret mode off the TPU). The kernel's tile
+    ``(bn, bk)`` follows ``quant_matmul``'s shape rule
+    (:func:`_widest_tile`) for the whole ``(M, bn)`` output stripe; K
+    and N are zero-padded to it (zero words decode to 0.0)."""
     spec = _dense_wire_spec(fmt)
-    if use_kernel is None:
-        use_kernel = not interpret_default()
-    if not use_kernel:
-        w = spec.decode_tile(w_words, dtype=jnp.float32)
-        return jax.lax.ragged_dot(x.astype(jnp.float32), w, group,
-                                  precision=jax.lax.Precision.HIGHEST)
     m, k0 = x.shape
     n0 = w_words.shape[-1]
     if m % bm or m * takum_matmul.DEFAULT_BN * 4 > \

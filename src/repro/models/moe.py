@@ -12,11 +12,11 @@ Dispatch has no capacity and drops nothing: the selected (token, held
 expert) pairs become rows, sorted by expert, each expert's group padded
 to whole ``EXPERT_BM``-row tiles, and the SwiGLU's three projections run
 as grouped matmuls over that layout: ``ops.expert_matmul`` for wire
-stacks (``ops.ExpertWire``: the Pallas kernel decodes each expert's
-weight tiles in VMEM), ``lax.ragged_dot`` for float stacks. Each token's
-result is gathered back from its own rows and summed in the order of its
-selections, so it depends on that token alone, whatever else shares the
-step.
+stacks on the TPU (``ops.ExpertWire``: the Pallas kernel decodes each
+expert's weight tiles in VMEM), ``lax.ragged_dot`` for float stacks and
+for decoded wire stacks elsewhere. Each token's result is gathered back
+from its own rows and summed in the order of its selections, so it
+depends on that token alone, whatever else shares the step.
 
 Scoring (``ModelConfig.router_scoring``):
 
@@ -141,13 +141,18 @@ def rows_bound(n: int, k: int, held: int, bm: int) -> int:
 
 def _grouped(xs, w, group, tile_expert, n_used):
     """``xs [M, K]`` grouped rows times their expert's ``w`` -> ``[M, N]``
-    in ``xs``'s dtype."""
+    in ``xs``'s dtype. A wire stack runs the Pallas kernel on the TPU;
+    elsewhere its words are decoded and the rows multiplied in float32,
+    as the kernel does."""
+    dtype = xs.dtype
     if isinstance(w, ops.ExpertWire):
-        out = ops.expert_matmul(xs, w.words, group, tile_expert, n_used,
-                                w.spec, bm=EXPERT_BM)
-    else:
-        out = jax.lax.ragged_dot(xs, w.astype(xs.dtype), group)
-    return out.astype(xs.dtype)
+        if not ops.interpret_default():
+            return ops.expert_matmul(xs, w.words, tile_expert, n_used,
+                                     w.spec, bm=EXPERT_BM).astype(dtype)
+        xs, w = xs.astype(jnp.float32), w.decode(jnp.float32)
+    out = jax.lax.ragged_dot(xs, w.astype(xs.dtype), group,
+                             precision=jax.lax.Precision.HIGHEST)
+    return out.astype(dtype)
 
 
 def _routed(params, x, idx, g, first: int, held: int):

@@ -164,20 +164,19 @@ def _layout_case(counts, held=4, bm=8, k=16, n=32):
                                     [0, 0, 0, 32], [0, 0, 0, 0]])
 def test_expert_matmul_kernel_matches_its_oracle(counts):
     """Interpret-mode kernel == the jnp oracle bit for bit, with an empty
-    expert, one expert taking every row, and no rows at all; and the XLA
-    fallback (``lax.ragged_dot`` of the decoded words) to f32 rounding."""
+    expert, one expert taking every row, and no rows at all; and the
+    layer's path off the TPU (``lax.ragged_dot`` of the decoded words) to
+    f32 rounding."""
     x, w, group, te, nu = _layout_case(counts)
     spec = "takum8"
-    got = ops.expert_matmul(x, w, group, te, nu, spec, bm=8,
-                            use_kernel=True, interpret=True)
+    got = ops.expert_matmul(x, w, te, nu, spec, bm=8, interpret=True)
     _, bn, bk = ops.expert_blocks(x.shape[0], x.shape[1], w.shape[-1])
     xp = jnp.pad(x, ((0, 0), (0, bk - x.shape[1])))
     wp = jnp.pad(w, ((0, 0), (0, bk - w.shape[1]), (0, bn - w.shape[2])))
     want = kref.expert_matmul_ref(xp, wp, te, nu, spec, bm=8, bn=bn,
                                   bk=bk)[:, :w.shape[2]]
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    fallback = ops.expert_matmul(x, w, group, te, nu, spec, bm=8,
-                                 use_kernel=False)
+    fallback = moe._grouped(x, ops.ExpertWire(w, spec), group, te, nu)
     np.testing.assert_allclose(np.asarray(got), np.asarray(fallback),
                                rtol=1e-5, atol=1e-5)
     if not sum(counts):
@@ -236,3 +235,17 @@ def test_more_tokens_than_one_grouped_call_route_in_chunks():
     np.testing.assert_array_equal(np.asarray(whole),
                                   np.concatenate([a, b], axis=1))
     assert int(stats["moe_rows"]) == int(sa["moe_rows"]) + int(sb["moe_rows"])
+
+
+def test_param_counts_follow_the_layer_types():
+    """At the published widths, K-EXAONE-236B-A23B's 48 layers (dense
+    layer 0, then 128 routed experts of 2048 and a shared one) count
+    236.6 B parameters, 23.7 B of them active a token, as its name
+    says; the experts held here do not change the model's count."""
+    cfg = tiny(n_layers=48, d_model=6144, n_heads=64, n_kv_heads=8,
+               head_dim=128, d_ff=18432, d_expert=2048, n_experts=128,
+               experts_first=0, experts_held=16, vocab=153600,
+               layer_types=LLLG * 12,
+               mlp_layer_types=["dense"] + ["sparse"] * 47)
+    assert 236.5e9 < cfg.param_count() < 236.7e9
+    assert 23.6e9 < cfg.active_param_count() < 23.7e9

@@ -171,9 +171,9 @@ def test_expert_matmul_lowers(one_chip, tokens, k, n):
     spec = formats.get("takum8")
     txt = _compile(
         one_chip,
-        lambda x, w, g, te, nu: ops.expert_matmul(
-            x, w, g, te, nu, spec, use_kernel=True, interpret=False),
+        lambda x, w, te, nu: ops.expert_matmul(
+            x, w, te, nu, spec, interpret=False),
         ((m, k), jnp.bfloat16), ((EXP_HELD, k, n), jnp.uint8),
-        ((EXP_HELD,), jnp.int32), ((m // ops.EXPERT_BM,), jnp.int32),
+        ((m // ops.EXPERT_BM,), jnp.int32),
         ((1,), jnp.int32))
     assert "expert_matmul" in txt
