@@ -647,6 +647,44 @@ def takum_to_float(words, n: int, dtype=jnp.float32):
     return jax.lax.bitcast_convert_type(word, dt)
 
 
+def takum8_to_float(words, dtype=jnp.float32):
+    """Closed-form decode of 8-bit linear takum words to float32/bfloat16.
+
+    Bit-identical to ``takum_to_float(words, 8, dtype)``, in a few dozen
+    integer lane ops instead of the generic width's ~180: at n = 8 the
+    fraction (wf = 7 bits) always fits f32's 23, so none of the generic
+    RNE or int->float emulation can fire. A negative word is the two's
+    complement of its magnitude, so the decode sign-extends, takes the
+    absolute value ``a = D RRR TTT`` and reads it as positive. With
+    ``r = R if D else 7 - R``, the r characteristic bits and 3 - r
+    mantissa bits of the tail T land in the f32 word as
+    ``T << (20 + r)``, above ghost zeros, and the characteristic's bias
+    adds ``2^r + 126`` (D = 1) or ``128 - 2^(r+1)`` (D = 0) to the
+    exponent field; so the magnitude's top 12 bits are
+    ``1008 + ((T + 8) << r)`` or ``1024 + ((T - 16) << r)``. That field
+    overflows to inf past 2^127; it reaches 0 or below only for a <= 8,
+    whose values are 2^-127 (a = 8), 2^-143 (a = 7) and flushed to zero
+    (a < 7, zero itself at a = 0). NaR (a = 128) gives f32's NaN.
+    Integer-only: shifts, adds, compares and one bitcast.
+    """
+    dt = jnp.dtype(dtype)
+    if dt != jnp.dtype(jnp.float32):
+        return takum8_to_float(words, jnp.float32).astype(dtype)
+    hi = jnp.asarray(words).astype(jnp.int32) << 24   # word's sign at bit 31
+    a = jnp.abs(hi >> 24)                     # magnitude word, 0..128
+    d0 = a < 64                               # D = 0
+    reg = (a >> 3) & 7
+    r = jnp.where(d0, 7 - reg, reg)
+    base = (a & 7) + jnp.where(d0, -16, 8)
+    top = (base << r) + jnp.where(d0, 1024, 1008)
+    mag = jnp.minimum(top, 0x7F8) << 20       # exponent | fraction, inf cap
+    tiny = jnp.where(a == 8, 1 << 22, jnp.where(a == 7, 1 << 6, 0))
+    mag = jnp.where(a <= 8, tiny, mag)
+    bits = mag | (hi & jnp.int32(-1 << 31))
+    bits = jnp.where(a == 128, 0x7FC0_0000, bits)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
 def takum_to_float_ref(words, n: int, dtype=jnp.float32):
     """Reference ldexp/divide reconstruction — the pre-integer-path
     implementation, retained as the oracle for the bit-exactness tests.

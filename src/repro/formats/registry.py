@@ -203,11 +203,12 @@ class FormatSpec:
 def lut_enabled() -> bool:
     """Whether LUT decode hooks are active for this process.
 
-    Off unless ``REPRO_LUT_DECODE=1``. No measurement has shown the
-    table to win: XLA:CPU lowers the gather serially and loses to the
-    computed integer decode (~20x at 2M elements, docs/formats.md), and
-    it has not been timed on a TPU. Read per call (trace-time only), so
-    tests can flip the env var without cache invalidation games.
+    Off unless ``REPRO_LUT_DECODE=1``. XLA:CPU lowers the gather
+    serially and loses to the computed integer decode (~20x at 2M
+    elements); on a TPU v5e posit8's table beats its computed decode
+    1.9x in the codec-only kernel (docs/formats.md), which no serving
+    workload has judged yet. Read per call (trace-time only), so tests
+    can flip the env var without cache invalidation games.
     """
     return os.environ.get("REPRO_LUT_DECODE", "") == "1"
 
@@ -217,8 +218,15 @@ def lut_enabled() -> bool:
 # ---------------------------------------------------------------------------
 
 
+# targets of takum8's closed-form decode (bit-identical to the generic
+# one; a bfloat16 result is the float32 one cast, as there)
+_TAKUM8_DTYPES = (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16))
+
+
 def _takum_decode(words, n, dtype=jnp.float32):
     from repro.core import takum
+    if n == 8 and jnp.dtype(dtype) in _TAKUM8_DTYPES:
+        return takum.takum8_to_float(words, dtype=dtype)
     return takum.takum_to_float(words, n, dtype=dtype)
 
 
@@ -320,11 +328,12 @@ def _make(kind: str, n: int) -> FormatSpec:
         raise ValueError(f"format kind {kind!r} needs a word width n, "
                          f"got {n!r}")
     hooks = dict(_KIND_HOOKS[kind])
-    # LUT tile codec: only posit8 carries one. takum8's computed decode is
-    # a fixed-window integer dataflow that already beats the gather on
-    # every backend we measured, so "where it wins" is: nowhere (see
-    # docs/formats.md); posit8's full-width CLZ + shifts lose to one
-    # gather on the TPU VPU.
+    # LUT tile codec: only posit8 carries one. takum8 needs no table: on
+    # one TPU v5e its closed-form decode runs the codec-only kernel at
+    # 32.1 G words/s, against 16.1 for the generic-width decode and, for
+    # posit8, 15.1 computed and 28.6 from the table (docs/formats.md).
+    # The table stays behind REPRO_LUT_DECODE until a posit8 workload
+    # judges it end to end.
     if kind == "posit" and n == 8:
         hooks["_lut"] = _posit8_lut_decode
     return FormatSpec(name=_KIND_NAME[kind].format(n=n), kind=kind, n=n,

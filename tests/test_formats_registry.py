@@ -102,6 +102,43 @@ def test_identity_codec_is_registered():
                                   np.asarray(x))
 
 
+def _decode_tile_ops(spec, shape=(64, 256)):
+    """Tile-shaped equations in the flattened jaxpr of ``decode_tile`` on
+    a ``DEFAULT_STRIP`` of words: the VPU work per word. Broadcasts of
+    scalar constants are not counted."""
+    jaxpr = jax.make_jaxpr(spec.decode_tile)(
+        jax.ShapeDtypeStruct(shape, spec.word_dtype)).jaxpr
+
+    def count(jx):
+        total = 0
+        for eqn in jx.eqns:
+            subs = [getattr(v, "jaxpr", v) for v in eqn.params.values()
+                    if hasattr(v, "jaxpr") or hasattr(v, "eqns")]
+            if subs:
+                total += sum(count(sub) for sub in subs)
+            elif (eqn.primitive.name != "broadcast_in_dim"
+                  and any(o.aval.shape == shape for o in eqn.outvars)):
+                total += 1
+        return total
+
+    return count(jaxpr)
+
+
+def test_takum8_decode_takes_the_closed_form():
+    """takum8's ``decode_tile`` is the closed-form n = 8 decode, not the
+    generic width's (181 tile ops a word)."""
+    assert _decode_tile_ops(formats.resolve("takum8")) <= 64
+
+
+@pytest.mark.parametrize("name,ops_per_word", [("takum16", 179),
+                                               ("posit8", 190)])
+def test_other_decodes_keep_their_dataflow(name, ops_per_word, monkeypatch):
+    """Every other format keeps the decode it had: its op count is pinned
+    at the one before takum8 took the closed form."""
+    monkeypatch.delenv("REPRO_LUT_DECODE", raising=False)
+    assert _decode_tile_ops(formats.resolve(name)) == ops_per_word
+
+
 # ---------------------------------------------------------------------------
 # Kernel-vs-oracle parity, per registered format
 # ---------------------------------------------------------------------------

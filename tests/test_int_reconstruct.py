@@ -142,6 +142,47 @@ def test_integer_path_sampled_vs_golden_exact(n):
             (hex(int(T)), got[i], want)
 
 
+# ---------------------------------------------------------------------------
+# takum8's closed-form decode: the generic n = 8 decode, bit for bit
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("shape", [(256,), (2, 128), (64, 256)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_takum8_closed_form_matches_generic_decode(shape, dtype):
+    """Every word, in every layout a caller hands it: a flat vector, a
+    kernel's (R, 128) rows and a ``DEFAULT_STRIP`` (64, 256) strip."""
+    words = np.arange(int(np.prod(shape)), dtype=np.int64) % 256
+    jw = jnp.asarray(words.reshape(shape)).astype(jnp.uint8)
+    got = np.asarray(takum.takum8_to_float(jw, dtype))
+    want = np.asarray(takum.takum_to_float(jw, 8, dtype))
+    assert got.dtype == want.dtype == np.dtype(dtype)
+    u = np.uint16 if got.dtype.itemsize == 2 else np.uint32
+    np.testing.assert_array_equal(got.view(u), want.view(u))
+
+
+def test_takum8_closed_form_matches_ldexp_oracle_and_pins_specials():
+    words = np.arange(256, dtype=np.int64)
+    got = np.asarray(takum.takum8_to_float(jnp.asarray(words)
+                                           .astype(jnp.uint8)))
+    _assert_bits_equal(got, _np_ldexp_oracle(words, 8), words, 8)
+    bits = got.view(np.uint32)
+    assert bits[0x80] == 0x7FC0_0000                      # NaR -> NaN
+    assert bits[0] == 0                                   # +0.0
+    inf = np.nonzero(np.isinf(got))[0]                    # past 2^127
+    assert sorted(inf) == [*range(0x79, 0x80), *range(0x81, 0x88)]
+    assert (got[0x79:0x80] > 0).all() and (got[0x81:0x88] < 0).all()
+    assert got[0x78] == 2.0 ** 127 and got[0x88] == -(2.0 ** 127)
+    sub = {7: 2.0 ** -143, 8: 2.0 ** -127, 0xF9: -2.0 ** -143,
+           0xF8: -2.0 ** -127}                            # f32 subnormals
+    for w, v in sub.items():
+        assert bits[w] == np.float32(v).view(np.uint32), hex(w)
+    assert (bits[1:7] == 0).all()                         # flushed: +0.0
+    assert (bits[0xFA:] == 0x8000_0000).all()             # flushed: -0.0
+    tiny = np.abs(got) < np.finfo(np.float32).tiny
+    assert sorted(np.nonzero(tiny & (got != 0))[0]) == sorted(sub)
+
+
 def test_takum64_integer_path_subprocess():
     """x64 lanes: f64 output bit-identical to the numpy ldexp oracle at
     n = 64 (and f32 output from uint64 lanes at n = 48)."""
@@ -206,6 +247,7 @@ def _ast_audit(fn, *, allow_div_in: tuple = ()):
 
 def test_hot_paths_are_integer_only():
     _ast_audit(takum.takum_to_float)
+    _ast_audit(takum.takum8_to_float)
     _ast_audit(takum.float_to_takum)
     _ast_audit(takum._unbar)
     _ast_audit(takum._rne_shr)
